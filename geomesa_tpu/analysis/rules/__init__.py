@@ -25,7 +25,7 @@ from geomesa_tpu.analysis.rules import (
     gt008_conf_keys,
     gt009_slo_registries,
     gt010_blessed_spawn,
-    gt011_taxonomy_bypass,
+    gt011_classify_bypass,
     gt012_unbucketed_dims,
 )
 
@@ -40,7 +40,7 @@ ALL_RULES = (
     gt008_conf_keys,
     gt009_slo_registries,
     gt010_blessed_spawn,
-    gt011_taxonomy_bypass,
+    gt011_classify_bypass,
     gt012_unbucketed_dims,
 )
 

@@ -1,11 +1,11 @@
 """GT011: serving-path ``except Exception`` that bypasses the fault
-taxonomy.
+classification.
 
-The resilience layer (PR 7) threads ONE taxonomy through the serving
+The resilience layer (PR 7) threads ONE classification through the serving
 path: every fault is classified (``resilience.classify``) and then
 retried, degraded (``note_degraded``) or surfaced typed. A handler that
 catches ``Exception`` (or bare ``except``) and neither re-raises, nor
-routes through the taxonomy, nor even USES the caught exception
+routes through the classification, nor even USES the caught exception
 swallows faults silently — the next device OOM or corrupt partition
 vanishes instead of degrading visibly. Scoped to the serving-path
 modules; an intentional swallow (best-effort observability, last-resort
@@ -46,8 +46,8 @@ _HOT_FILES = {
     "warmup.py",
 }
 
-#: taxonomy entry points: a call to any of these routes the fault
-_TAXONOMY_CALLS = {"classify", "note_degraded", "is_oom"}
+#: classification entry points: a call to any of these routes the fault
+_CLASSIFY_CALLS = {"classify", "note_degraded", "is_oom"}
 
 _BROAD = {"Exception", "BaseException"}
 
@@ -76,7 +76,7 @@ def _routes_fault(handler: ast.ExceptHandler) -> bool:
         if isinstance(node, ast.Raise):
             return True
         if isinstance(node, ast.Call):
-            if terminal_name(node.func) in _TAXONOMY_CALLS:
+            if terminal_name(node.func) in _CLASSIFY_CALLS:
                 return True
         if bound and isinstance(node, ast.Name) and node.id == bound:
             return True
@@ -98,6 +98,6 @@ def check(ctx):
             node,
             "broad except swallows the fault without classify()/"
             "note_degraded()/re-raise (and never uses the exception) -- "
-            "route it through the resilience taxonomy, or justify the "
+            "route it through the resilience classification, or justify the "
             "swallow with a reasoned disable",
         )

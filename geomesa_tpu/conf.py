@@ -104,12 +104,6 @@ Properties:
                                 (everything in one jitted launch) or
                                 ``host`` (numpy radix local sorts + XLA
                                 all_to_all exchange)
-- ``compile.cache.dir``         persistent XLA compilation-cache
-                                directory for serving ("" = the
-                                GEOMESA_TPU_COMPILE_CACHE env /
-                                ~/.cache default; ``off`` disables) —
-                                wired at make_server / CLI serve start,
-                                hit/miss surfaced in /stats
 - ``compile.bucket.growth``     geometric ratio of the canonical
                                 compile-shape ladder (bucketing.py)
                                 every dynamic trace shape rounds up
@@ -466,9 +460,6 @@ _DEFS = {
     "mesh.devices": (0, int),
     "mesh.replicas": (1, int),
     "mesh.sort.engine": ("auto", _parse_sort_engine),
-    # persistent serving compile cache (jaxconf.py): directory override
-    # ("" = env/default resolution, "off" disables)
-    "compile.cache.dir": ("", str),
     # canonical compile-shape bucketing (bucketing.py): the geometric
     # capacity ladder every dynamic trace shape rounds up onto (growth
     # 2.0 = the historical next-power-of-two; <= 1 disables bucketing
@@ -599,7 +590,7 @@ _NON_PROP_ENV = frozenset(
         "GEOMESA_TPU_CTXCHECK",  # analysis/ctxcheck.py switch
         "GEOMESA_TPU_COMPILECHECK",  # analysis/compilecheck.py switch
         "GEOMESA_TPU_NO_NATIVE",  # native.py opt-out
-        "GEOMESA_TPU_COMPILE_CACHE",  # jaxconf.py cache dir override
+        "GEOMESA_TPU_COMPILE_CACHE",  # jaxconf.py persistent-cache off switch
     }
 )
 

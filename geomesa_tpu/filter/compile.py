@@ -542,6 +542,8 @@ class CompiledFilter:
                 mask_fn = jax.jit(self.device_fn)
                 count_fn = jax.jit(lambda c: self.device_fn(c).sum())
             self._jitted_scan = (count_fn, mask_fn)
+            # which engine serves this filter (read by chip_smoke.py)
+            self.scan_engine = "pallas" if scan is not None else "xla"
         return self._jitted_scan
 
     def host_mask(self, batch: FeatureBatch) -> np.ndarray:

@@ -120,8 +120,8 @@ def build_index_device(
         )
 
     # pad to a POWER-OF-TWO row bucket (then to a shard multiple): the
-    # encode + exchange jits retrace per input shape, and a ~30-60s
-    # remote compile per distinct flush size would dominate every flush.
+    # encode + exchange jits retrace per input shape, and a compile per
+    # distinct flush size would dominate every flush.
     # Bucketing bounds the shape set; the valid mask hides the padding.
     cap = 1 << max(n - 1, 0).bit_length()
     cap += (-cap) % n_shards

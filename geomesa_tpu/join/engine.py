@@ -111,9 +111,9 @@ class JoinIndex:
             import jax.numpy as jnp
 
             try:
-                from geomesa_tpu.jaxconf import scoped_x64
+                import jax
 
-                with scoped_x64():
+                with jax.enable_x64(True):
                     dev = {
                         k: jnp.asarray(v) for k, v in self.planes.items()
                     }
@@ -144,15 +144,9 @@ class JoinIndex:
             cap = local_n * shards
             sharding = NamedSharding(mesh, P(axis))
             out = {}
-            try:
-                from geomesa_tpu.jaxconf import scoped_x64
+            import jax
 
-                ctx = scoped_x64()
-            except Exception:  # pragma: no cover - platform without x64  # lint: disable=GT011(x64 capability probe: staging proceeds at platform precision by design)
-                from contextlib import nullcontext
-
-                ctx = nullcontext()
-            with ctx:
+            with jax.enable_x64(True):
                 for k, v in self.planes.items():
                     a = np.asarray(v, np.float64)  # lint: disable=GT004(host-side plane coercion BEFORE device_put: staging, not a device fetch)
                     if cap > self.n:
@@ -809,9 +803,9 @@ def _lane_ctx(exact: bool):
         from contextlib import nullcontext
 
         return nullcontext()
-    from geomesa_tpu.jaxconf import scoped_x64
+    import jax
 
-    return scoped_x64()
+    return jax.enable_x64(True)
 
 
 def _stage_envs(envs: np.ndarray, dt: np.dtype):
@@ -830,9 +824,9 @@ def _stage_envs(envs: np.ndarray, dt: np.dtype):
         env_host[:, 3] = np.nextafter(env_host[:, 3], dt.type(np.inf))
         return jnp.asarray(env_host)
     try:
-        from geomesa_tpu.jaxconf import scoped_x64
+        import jax
 
-        with scoped_x64():
+        with jax.enable_x64(True):
             out = jnp.asarray(env_host)
         if out.dtype == np.float64:
             return out

@@ -61,15 +61,24 @@ def build_density_pallas(
     from jax.experimental import pallas as pl
 
     LANES = 128
-    # weighted: float32 one-hots; (cells, R) f32 temporaries cap R at
-    # 2048 inside the ~16MB VMEM budget. Unweighted int8 fits 4x that.
-    R = rows_per_step or (2048 if weighted else 8192)
-    assert R % LANES == 0
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
     # sublane-pad the one-hot cell axes (int8 tiles are (32, 128))
     HP = max(32, -(-height // 32) * 32)
     WP = max(32, -(-width // 32) * 32)
+    if rows_per_step is None:
+        if weighted:
+            # the two (cells, R) float32 one-hots must fit the compiler's
+            # VMEM stack: (HP + WP) * R * 4B <= 6MiB (v5e refused 512x512
+            # at R=2048, 8MiB; 384x384 and 512x256 compile at 2048)
+            R = 2048
+            while R > LANES and (HP + WP) * R * 4 > 6 << 20:
+                R //= 2
+        else:
+            R = 8192  # int8 one-hots: 4x the f32 rows per step
+    else:
+        R = rows_per_step
+    assert R % LANES == 0
     oh_dtype = jnp.float32 if weighted else jnp.int8
     acc_dtype = jnp.float32 if weighted else jnp.int32
     prec = (

@@ -239,7 +239,7 @@ def mesh_count_kernel(mesh, axis: str, C: int, n_planes: int,
 
     from jax.sharding import PartitionSpec as P
 
-    from geomesa_tpu.parallel.dist import shard_map
+    from jax import shard_map
 
     key = ("mesh-count", mesh_key(mesh), axis, C, n_planes, gated,
            np.dtype(dtype).str)
@@ -285,7 +285,7 @@ def mesh_join_kernel(mesh, axis: str, C: int, cap: int, n_planes: int,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from geomesa_tpu.parallel.dist import shard_map
+    from jax import shard_map
 
     key = ("mesh", mesh_key(mesh), axis, C, cap, n_planes, gated,
            np.dtype(dtype).str)

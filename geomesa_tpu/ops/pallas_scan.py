@@ -211,7 +211,9 @@ def build_pallas_scan(
 
     Returns ``(count_fn, mask_fn, cols)`` where each fn takes a dict of
     staged 1-D device columns (see ops/scan.stage_columns) and returns the
-    int32 hit count / bool mask for the whole array. Raises
+    int32 hit count / bool mask for the whole array. Either fn takes an
+    optional bool ``valid`` plane (the padded buffers of a streaming
+    resident index): rows where it is False never match. Raises
     PallasUnsupported when the filter can't be tiled; callers fall back to
     CompiledFilter.device_fn.
     """
@@ -226,7 +228,7 @@ def build_pallas_scan(
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
 
-    def _prep(coldict):
+    def _prep(coldict, valid):
         n = int(_tile_shape(coldict)[0])
         if n > 2**31 - 1 - br * LANES:
             raise PallasUnsupported("partition too large for int32 indexing")
@@ -236,31 +238,46 @@ def build_pallas_scan(
             jnp.pad(coldict[c], (0, pad)).reshape(grid * br, LANES)
             for c in cols
         ]
+        if valid is not None:
+            # int8 tile (Mosaic has no bool memref); sublane rows are a
+            # multiple of 32, the int8 tiling
+            mats.append(
+                jnp.pad(valid.astype(jnp.int8), (0, pad)).reshape(
+                    grid * br, LANES
+                )
+            )
         return n, grid, pad, mats
 
-    def _valid_mask(n):
-        # rows past n (tile padding) must not count as hits
-        def tail(m):
+    def _valid_mask(n, has_valid):
+        # rows past n (tile padding), and rows the validity plane marks
+        # dead, must not count as hits
+        def tail(m, in_refs):
             i = pl.program_id(0)
             idx = (
                 i * br * LANES
                 + jax.lax.broadcasted_iota(jnp.int32, (br, LANES), 0) * LANES
                 + jax.lax.broadcasted_iota(jnp.int32, (br, LANES), 1)
             )
-            return m & (idx < n)
+            m = m & (idx < n)
+            if has_valid:
+                m = m & (in_refs[-1][...] != 0)
+            return m
 
         return tail
 
     # index-map literals must be int32: under x64 a bare python 0 traces
     # as an i64 constant, which Mosaic refuses to lower
     _zero = lambda: jnp.int32(0)
-    _in_specs = [
-        pl.BlockSpec((br, LANES), lambda i: (i, _zero())) for _ in cols
-    ]
 
-    def count_fn(coldict):
-        n, grid, pad, mats = _prep(coldict)
-        tail = _valid_mask(n)
+    def _in_specs(n_in):
+        return [
+            pl.BlockSpec((br, LANES), lambda i: (i, _zero()))
+            for _ in range(n_in)
+        ]
+
+    def count_fn(coldict, valid=None):
+        n, grid, pad, mats = _prep(coldict, valid)
+        tail = _valid_mask(n, valid is not None)
 
         def kernel(*refs):
             # TPU grids run sequentially per core, so a single (1, LANES)
@@ -271,7 +288,9 @@ def build_pallas_scan(
             # injects an int64 convert Mosaic cannot lower. The axis-0
             # reduce keeps a (1, LANES) vector and lowers directly.
             *in_refs, out_ref = refs
-            m = tail(tile_fn({c: r[...] for c, r in zip(cols, in_refs)}))
+            m = tail(
+                tile_fn({c: r[...] for c, r in zip(cols, in_refs)}), in_refs
+            )
 
             @pl.when(pl.program_id(0) == 0)
             def _():
@@ -284,7 +303,7 @@ def build_pallas_scan(
         partials = pl.pallas_call(
             kernel,
             grid=(grid,),
-            in_specs=_in_specs,
+            in_specs=_in_specs(len(mats)),
             out_specs=pl.BlockSpec((1, LANES), lambda i: (_zero(), _zero())),
             out_shape=jax.ShapeDtypeStruct((1, LANES), jnp.int32),
             interpret=interpret,
@@ -292,19 +311,21 @@ def build_pallas_scan(
         # final 128-way fold runs in XLA outside the kernel
         return jnp.sum(partials, dtype=jnp.int32)
 
-    def mask_fn(coldict):
-        n, grid, pad, mats = _prep(coldict)
-        tail = _valid_mask(n)
+    def mask_fn(coldict, valid=None):
+        n, grid, pad, mats = _prep(coldict, valid)
+        tail = _valid_mask(n, valid is not None)
 
         def kernel(*refs):
             *in_refs, out_ref = refs
-            m = tail(tile_fn({c: r[...] for c, r in zip(cols, in_refs)}))
+            m = tail(
+                tile_fn({c: r[...] for c, r in zip(cols, in_refs)}), in_refs
+            )
             out_ref[...] = m.astype(jnp.int8)
 
         m = pl.pallas_call(
             kernel,
             grid=(grid,),
-            in_specs=_in_specs,
+            in_specs=_in_specs(len(mats)),
             out_specs=pl.BlockSpec((br, LANES), lambda i: (i, _zero())),
             out_shape=jax.ShapeDtypeStruct((grid * br, LANES), jnp.int8),
             interpret=interpret,

@@ -564,7 +564,7 @@ def build_z3_dimscan_rt(
     values fold into the mask data-dependently (so Mosaic cannot elide
     the reads) but never change the result for nonzero fill. Padding
     the 12B/row kernel to 16B/row this way settles whether the scan is
-    bandwidth-bound or row-rate-bound (VERDICT r4 next-6): if rows/s
+    bandwidth-bound or row-rate-bound: if rows/s
     holds while bytes/row grows, the bound is per-row VPU ops, and the
     12B kernel's lower HBM%% is arithmetic, not inefficiency.
     """

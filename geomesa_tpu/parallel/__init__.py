@@ -18,7 +18,6 @@ Everything compiles against virtual CPU meshes for tests and dry runs.
 
 from geomesa_tpu.parallel.mesh import make_mesh, serving_mesh
 from geomesa_tpu.parallel.dist import (
-    shard_map,
     sharded_count_scan,
     distributed_sort,
     distributed_z3_sort,
@@ -34,7 +33,6 @@ from geomesa_tpu.parallel.multihost import (
 __all__ = [
     "make_mesh",
     "serving_mesh",
-    "shard_map",
     "sharded_count_scan",
     "distributed_sort",
     "distributed_z3_sort",

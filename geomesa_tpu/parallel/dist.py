@@ -55,41 +55,10 @@ _SENTINEL = 0xFFFFFFFF
 
 # jitted exchange-step cache: a fresh ``jax.jit(step)`` per call would
 # RE-COMPILE the whole exchange on every invocation (jit's in-memory
-# cache lives on the wrapper object) — ~30-60s per flush through the
-# remote TPU compiler. Keyed by every static the step closure bakes in;
+# cache lives on the wrapper object) — a full compile per flush.
+# Keyed by every static the step closure bakes in;
 # input shapes are handled by the cached wrapper's own jit cache.
 _STEP_CACHE: dict = {}
-
-
-# -- jax.shard_map version shim ----------------------------------------------
-
-_SHARD_MAP = None
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exports ``shard_map`` at top level with a ``check_vma``
-    flag; older installs only have ``jax.experimental.shard_map`` whose
-    equivalent flag is ``check_rep``. Without this shim those installs
-    fail at ``from jax import shard_map`` and the whole mesh path —
-    tests, dryrun, serving — errors at import instead of running."""
-    global _SHARD_MAP
-    if _SHARD_MAP is None:
-        import jax
-
-        sm = getattr(jax, "shard_map", None)
-        if sm is not None:
-            _SHARD_MAP = (sm, "check_vma")
-        else:  # pragma: no cover - exercised on older jax installs
-            from jax.experimental.shard_map import shard_map as esm
-
-            _SHARD_MAP = (esm, "check_rep")
-    fn, flag = _SHARD_MAP
-    return fn(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{flag: check_vma},
-    )
 
 
 def _resolve_engine(engine: "str | None", mesh) -> str:
@@ -123,7 +92,7 @@ def sharded_count_scan(mesh, device_fn, cols: dict, axis: str = "shard"):
     }
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec,) * len(sharded_cols),
         out_specs=P(),
@@ -369,7 +338,7 @@ def _a2a_jitted(mesh, axis: str):
     if fn is None:
 
         @partial(
-            shard_map, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
+            jax.shard_map, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
             check_vma=False,
         )
         def step(blocks):
@@ -727,7 +696,7 @@ def _make_device_step(
         return ks_r, leaf_r, v_r, dropped
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec,) * (n_lanes + n_extras + has_valid),
         out_specs=((spec,) * (n_lanes + n_extras) + (spec, P(), P())),
@@ -924,7 +893,7 @@ def sharded_query_scan(
     cap = local_n if cap_per_shard is None else min(cap_per_shard, local_n)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec,) * (1 + len(planes) + len(pay_planes)),
         out_specs=(spec, spec) + (spec,) * len(pay_planes) + (P(),),
@@ -976,7 +945,7 @@ def sharded_build_and_query_step(mesh, sfc, x, y, t, query_bounds, axis: str = "
     exchange + local sort, row ids riding as payload (index build) ->
     key-only zscan mask over the SORTED key lanes + row-id compaction +
     gather (query THROUGH the built index, so key corruption in the
-    exchange is caught — VERDICT round-2 weak #6), plus the exact
+    exchange is caught), plus the exact
     pre-sort coordinate count as an independent cross-check.
 
     Returns (sorted_hi, sorted_lo, valid, exact_count, key_count,
@@ -995,7 +964,7 @@ def sharded_build_and_query_step(mesh, sfc, x, y, t, query_bounds, axis: str = "
     xmin, ymin, xmax, ymax, tmin, tmax = query_bounds
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, P()),

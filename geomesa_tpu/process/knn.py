@@ -70,8 +70,7 @@ def knn(
         # TPU-native path: a fully resident cache answers kNN in ONE
         # fused dispatch (distance + mask + lax.top_k) — the expanding
         # windows below exist for the STORE path, where each probe pays
-        # a column (re)staging; porting them to the resident cache was
-        # VERDICT round-3 missing item 2
+        # a column (re)staging
         got = device_index.knn(
             px, py, k,
             query=None if base is ast.Include else base,

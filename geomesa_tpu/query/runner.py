@@ -96,7 +96,7 @@ def _scan_run(built, compiled, jitted, start: int, stop: int,
     recovers by HALVING the run and retrying each half — a transient
     memory squeeze (concurrent staging, fragmentation) costs extra
     launches, not the query; anything else propagates to the fault
-    taxonomy upstream."""
+    classification upstream."""
     from geomesa_tpu.failpoints import FailpointError, fail_point
     from geomesa_tpu.tracing import span
 

@@ -5,14 +5,14 @@ not dying — GeoMesa's layered fallbacks (loose -> exact, stats -> scan)
 and the strategy switching "Adaptive Geospatial Joins for Modern
 Hardware" motivates [UNVERIFIED - empty reference mount]. PRs 1-6 built
 the layers (sched admission, prefetch pipeline, crash-consistent store,
-tracing, chunk pre-aggregates); this module threads ONE fault taxonomy
+tracing, chunk pre-aggregates); this module threads ONE fault classification
 through all of them so a failed device launch, a flaky disk or a
 saturated queue turns into a retried, degraded or typed answer instead
 of an unhandled 500.
 
 Three pieces:
 
-- **Fault taxonomy.** :func:`classify` maps any exception on the serving
+- **Fault classification.** :func:`classify` maps any exception on the serving
   path to ``RETRYABLE`` (transient — I/O hiccups, injected
   ``FailpointError``, non-OOM device runtime errors: retry with jittered
   backoff), ``DEGRADABLE`` (the work is lost but a cheaper rung can still
@@ -75,6 +75,7 @@ __all__ = [
     "device_breaker",
     "cache_breaker",
     "enabled",
+    "is_compile_refusal",
     "is_oom",
     "note_degraded",
     "partition_breaker",
@@ -127,16 +128,29 @@ def degrade_allowed() -> bool:
     return enabled() and bool(sys_prop("resilience.degrade"))
 
 
-# -- fault taxonomy ---------------------------------------------------------
+# -- fault classification ---------------------------------------------------------
+
+
+def is_compile_refusal(exc: BaseException) -> bool:
+    """The compiler refused a kernel: Mosaic could not lower it, or its
+    tiles do not fit the core's fast memory (VMEM). Such a refusal
+    reads RESOURCE_EXHAUSTED like a device OOM, but it is a bug in the
+    kernel's shape rules, not a load condition: the same kernel fails
+    the same way at any load, so it is FATAL, never degraded away."""
+    s = str(exc)
+    return "memory space vmem" in s or "Mosaic failed to compile" in s
 
 
 def is_oom(exc: BaseException) -> bool:
-    """Device/host memory exhaustion — XLA surfaces HBM OOM as
-    RESOURCE_EXHAUSTED XlaRuntimeErrors; staging can also hit host
-    MemoryError. OOM is special-cased by the scan paths: halve the
-    batch and retry before degrading."""
+    """Device/host memory exhaustion — XLA surfaces HBM OOM as a
+    RESOURCE_EXHAUSTED ``jax.errors.JaxRuntimeError``; staging can also
+    hit host MemoryError. OOM is special-cased by the scan paths: halve
+    the batch and retry before degrading. A compiler's VMEM refusal is
+    not an OOM (:func:`is_compile_refusal`)."""
     if isinstance(exc, MemoryError):
         return True
+    if is_compile_refusal(exc):
+        return False
     s = str(exc)
     return (
         "RESOURCE_EXHAUSTED" in s
@@ -157,6 +171,8 @@ def classify(exc: BaseException) -> str:
         return FATAL
     if isinstance(exc, (LaunchStuckError, PartitionUnavailableError)):
         return DEGRADABLE
+    if is_compile_refusal(exc):
+        return FATAL
     if is_oom(exc):
         return DEGRADABLE
     try:
@@ -170,7 +186,9 @@ def classify(exc: BaseException) -> str:
         return FATAL  # a real state (GC'd generation) -- refresh, not retry
     if isinstance(exc, OSError):
         return RETRYABLE  # incl. FailpointError -- transient injection
-    if type(exc).__name__ == "XlaRuntimeError":
+    import jax
+
+    if isinstance(exc, jax.errors.JaxRuntimeError):
         return RETRYABLE  # transient device runtime fault (non-OOM)
     if isinstance(exc, (ValueError, KeyError, TypeError)):
         return FATAL  # bad request / programming error: surface loudly

@@ -2346,9 +2346,9 @@ def make_server(
     expires. Requires the streaming live layer (the WAL is the thing
     being shipped).
 
-    The persistent XLA compile cache is wired here from the
-    ``compile.cache.dir`` conf key (serving is compile-heavy; a
-    restarted server warms from disk) — hit/miss counts ride
+    The persistent XLA compile cache is wired here (jaxconf's one
+    location rule; serving is compile-heavy, a restarted server warms
+    from disk) — hit/miss counts ride
     ``/stats`` and the ``geomesa_compile_cache_*`` metrics."""
     import os as _os
 

@@ -25,6 +25,9 @@ invariants mid-run plus the seeded detections.
 
 import os
 
+# no persistent compile cache: the suite writes nothing into the tree
+# (the checkout is what gets copied to the chip); subprocesses inherit it
+os.environ["GEOMESA_TPU_COMPILE_CACHE"] = "off"
 os.environ.setdefault("GEOMESA_TPU_LOCKCHECK", "1")
 os.environ.setdefault("GEOMESA_TPU_CTXCHECK", "1")
 os.environ.setdefault("GEOMESA_TPU_COMPILECHECK", "1")

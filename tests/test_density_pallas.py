@@ -1,4 +1,4 @@
-"""Pallas density kernel (VERDICT round-3 item 3): pixel histograms as
+"""Pallas density kernel: pixel histograms as
 one-hot MXU contractions must match the scatter engine and the host
 oracle, for weighted and unweighted grids, odd grid shapes, empty inputs,
 and through DeviceIndex.density / the process surface.

@@ -146,7 +146,7 @@ def test_detach_live_listener():
     assert len(calls) == n_after_put  # no refresh after detach
 
 
-# -- streaming delta refresh (VERDICT round-1 item 9) -----------------------
+# -- streaming delta refresh -----------------------
 
 
 def _oracle(ds, ecql):
@@ -232,6 +232,40 @@ class TestStreamingDeviceIndex:
         assert len(di) == 3900
         got = di.query("BBOX(geom, 169, 79, 171, 81)")
         assert set(got.fids.astype(np.int64).tolist()) >= set(range(50))
+
+    def test_pallas_engine_ands_the_validity_plane(self, monkeypatch):
+        """On a TPU the exact filter runs on the Pallas tile kernel even
+        for the padded streaming buffers (the server's resident index):
+        the kernel ANDs the validity plane, so evicted rows never count.
+        The CPU picks XLA, so the TPU choice is forced here (interpret
+        mode runs the same kernel code)."""
+        import jax
+
+        from geomesa_tpu.device_cache import StreamingDeviceIndex
+        from geomesa_tpu.filter.compile import CompiledFilter
+
+        def tpu_jitted_scan(self):
+            if not hasattr(self, "_jitted_scan"):
+                count_fn, mask_fn = self.pallas_scan()
+                self._jitted_scan = (jax.jit(count_fn), jax.jit(mask_fn))
+                self.scan_engine = "pallas"
+            return self._jitted_scan
+
+        monkeypatch.setattr(CompiledFilter, "jitted_scan", tpu_jitted_scan)
+        ds = _store(n=4000)
+        di = StreamingDeviceIndex(ds, "t", capacity=1 << 13)
+        di.evict(np.arange(1000, 1500))
+        assert di._device_valid() is not None
+        all_batch, expect = _oracle(ds, self.ECQL)
+        live = ~np.isin(all_batch.fids.astype(np.int64), np.arange(1000, 1500))
+        assert di.count(self.ECQL) == int((expect & live).sum())
+        assert di._compiled[repr(parse_ecql(self.ECQL))][0].scan_engine == (
+            "pallas"
+        )
+        np.testing.assert_array_equal(
+            np.sort(di.query(self.ECQL).fids.astype(np.int64)),
+            np.sort(all_batch.fids[expect & live].astype(np.int64)),
+        )
 
     def test_residual_and_host_filters_respect_validity(self):
         from geomesa_tpu.device_cache import StreamingDeviceIndex
@@ -759,7 +793,7 @@ def test_nonpoint_loose_stats_fused():
 
 
 def test_staging_device_encode_matches_numpy_oracle():
-    """VERDICT round-2 weak #4: staging encodes keys on DEVICE; planes
+    """Staging encodes keys on DEVICE; planes
     must be bit-identical to the host numpy oracle for every kind."""
     from geomesa_tpu.device_cache import _z_planes_np
 
@@ -818,7 +852,7 @@ def test_staging_device_encode_z2_and_x64_scoping():
         np.testing.assert_array_equal(np.asarray(di._cols[k]), v)
 
 
-# -- pushdown density + BIN (VERDICT round-2 item 3) -------------------------
+# -- pushdown density + BIN -------------------------
 
 
 class TestFusedDensityAndBin:
@@ -924,7 +958,7 @@ class TestFusedDensityAndBin:
         assert not np.array_equal(g1, g2)  # different windows, real effect
 
 
-# -- per-auth resident serving (VERDICT round-2 item 7) -----------------------
+# -- per-auth resident serving -----------------------
 
 
 class TestPerAuthResident:

@@ -1,4 +1,4 @@
-"""Dim-plane resident key scans (VERDICT round-3 item 1): the
+"""Dim-plane resident key scans: the
 de-interleaved z3 layout (nx, ny, packed bt) must serve DeviceIndex's
 loose path with exact parity against the interleaved masked-compare
 engine and the host oracle, across binned windows, streaming appends

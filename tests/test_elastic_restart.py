@@ -1,6 +1,6 @@
 """Elastic restart: device state is a cache; durable storage is truth.
 
-VERDICT round-1 item 7 / SURVEY section 5 failure-elastic story: the
+SURVEY section 5 failure-elastic story: the
 design claims a process can die and be rebuilt from Parquet + partition
 manifest (persisted layer) + durable log replay (recent live writes).
 This proves it end-to-end: build a DeviceIndex over an FS store plus a

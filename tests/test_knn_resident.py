@@ -1,4 +1,4 @@
-"""One-dispatch resident kNN (VERDICT round-3 item 2): DeviceIndex.knn
+"""One-dispatch resident kNN: DeviceIndex.knn
 is a single fused distance + mask + lax.top_k dispatch; it must match the
 expanding-window store search (ref KNNQuery, SURVEY section 2.4
 [UNVERIFIED - empty reference mount]) on results, tie rules, radius caps,

@@ -170,7 +170,7 @@ def test_shard_ranges_are_contiguous_z_ranges():
 def test_mesh_build_degrades_to_host_sort(monkeypatch):
     """A mesh-sort fault must not fail staging: the build falls back to
     the host lexsort (identical layout), counts the fallback and keeps
-    serving — PR 7's taxonomy applied to the build path."""
+    serving — PR 7's classification applied to the build path."""
     from geomesa_tpu import metrics
     from geomesa_tpu.parallel import dist
 
@@ -307,12 +307,12 @@ def test_mesh_server_endpoints():
 
 
 def test_mesh_conf_keys_declared():
-    """GT008 contract: the mesh.* / compile cache keys resolve and the
-    engine key validates."""
+    """GT008 contract: the mesh.* keys resolve and the engine key
+    validates."""
     from geomesa_tpu.conf import declared_keys, sys_prop
 
     for key in ("mesh.enabled", "mesh.devices", "mesh.replicas",
-                "mesh.sort.engine", "compile.cache.dir"):
+                "mesh.sort.engine"):
         assert key in declared_keys()
         sys_prop(key)
     with prop_override("mesh.sort.engine", "host"):

@@ -4,7 +4,6 @@ test plan)."""
 
 import numpy as np
 
-from geomesa_tpu.jaxconf import scoped_x64
 import pytest
 
 from geomesa_tpu.features.batch import FeatureBatch
@@ -74,7 +73,7 @@ def test_mosaic_mod_recursion_repro():
     old = sys.getrecursionlimit()
     sys.setrecursionlimit(20000)
     try:
-        with scoped_x64():
+        with jax.enable_x64(True):
 
             def kern_mod(x_ref, o_ref):
                 o_ref[...] = x_ref[...].astype(jnp.int32) % 2
@@ -106,7 +105,7 @@ def test_pip_kernel_parity_under_x64():
     batch = make_batch(rng, 4096)
     ecql = FILTERS[6]
     compiled = compile_filter(parse_ecql(ecql), SFT)
-    with scoped_x64():
+    with jax.enable_x64(True):
         scan = compiled.pallas_scan()
         assert scan is not None
         cols = stage_columns(batch, list(compiled.device_cols))
@@ -130,6 +129,22 @@ class TestPallasScanParity:
         assert got_mask.shape == expect.shape
         np.testing.assert_array_equal(got_mask, expect)
         assert int(count_fn(cols)) == int(expect.sum())
+
+    @pytest.mark.parametrize("ecql", FILTERS[:2] + FILTERS[6:7])
+    def test_validity_plane_masks_rows(self, rng, ecql):
+        """The padded buffers of a streaming resident index pass a bool
+        validity plane: dead rows never match, in count and mask."""
+        batch = make_batch(rng, 777)
+        cf = compile_filter(parse_ecql(ecql), SFT)
+        count_fn, mask_fn = cf.pallas_scan(block_rows=32)
+        cols = stage_columns(batch, cf.device_cols)
+        valid = rng.uniform(size=len(batch)) < 0.7
+        expect = cf.host_mask(batch) & valid
+        import jax.numpy as jnp
+
+        got = np.asarray(mask_fn(cols, jnp.asarray(valid)))
+        np.testing.assert_array_equal(got, expect)
+        assert int(count_fn(cols, jnp.asarray(valid))) == int(expect.sum())
 
     def test_single_partial_tile(self, rng):
         batch = make_batch(rng, 17)

@@ -200,7 +200,7 @@ def test_tube_and_proximity_resident_match_store_path():
 def test_tube_with_base_filter_stays_one_dispatch(monkeypatch):
     """A corridor query WITH a CQL base filter must still run the
     union-of-windows kernel (the base's compiled mask fuses into the
-    same dispatch — VERDICT round-3 weak #6: it used to fall back to the
+    same dispatch — it used to fall back to the
     76s-class per-segment store path) and match the store path exactly."""
     import numpy as np
 

@@ -1,4 +1,4 @@
-"""Fault-tolerant serving (ISSUE 7): fault taxonomy, circuit breakers,
+"""Fault-tolerant serving (ISSUE 7): fault classification, circuit breakers,
 launch watchdog, staging-OOM recovery, the degradation ladder,
 healthz/readyz + draining shutdown, adaptive Retry-After, and the chaos
 contract — every admitted request gets EXACTLY ONE response (success,
@@ -83,10 +83,10 @@ def _get_err(url):
         return e
 
 
-# -- fault taxonomy ---------------------------------------------------------
+# -- fault classification ---------------------------------------------------------
 
 
-def test_classify_taxonomy():
+def test_classify_fault_classes():
     from geomesa_tpu.sched.scheduler import DeadlineExpired, RejectedError
     from geomesa_tpu.store.fs import PartitionCorruptError
 
@@ -110,6 +110,45 @@ def test_classify_taxonomy():
     )
     assert C(PartitionCorruptError("bad crc")) == resilience.DEGRADABLE
     assert C(RuntimeError("anything else")) == resilience.FATAL
+
+
+def test_classify_jax_runtime_error_is_retryable():
+    """jax 0.9 raises device runtime faults as jax.errors.JaxRuntimeError
+    (the old XlaRuntimeError name is gone): a non-OOM one is transient,
+    a RESOURCE_EXHAUSTED one is an OOM."""
+    import jax
+
+    C = resilience.classify
+    assert (
+        C(jax.errors.JaxRuntimeError("INTERNAL: failed to execute"))
+        == resilience.RETRYABLE
+    )
+    assert (
+        C(jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: Out of memory "
+                                     "while trying to allocate 4.00G"))
+        == resilience.DEGRADABLE
+    )
+
+
+def test_vmem_compile_refusal_is_not_oom():
+    """A kernel the TPU compiler refuses for VMEM reads RESOURCE_EXHAUSTED
+    but is a bug, not a load condition: not an OOM, FATAL, never
+    degraded away (the weighted 512x512 density kernel was refused so)."""
+    import jax
+
+    e = jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+        "allocating on stack for %fn.1 = f32[512,512] custom-call(...), "
+        'custom_call_target="tpu_custom_call"'
+    )
+    assert resilience.is_compile_refusal(e)
+    assert not resilience.is_oom(e)
+    assert resilience.classify(e) == resilience.FATAL
+    hbm = jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Out of memory while trying to allocate 8.00G"
+    )
+    assert not resilience.is_compile_refusal(hbm)
+    assert resilience.is_oom(hbm)
 
 
 def test_backoff_sleeps_jitter_and_cumulative_cap():

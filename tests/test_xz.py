@@ -128,7 +128,7 @@ def _u64(hi, lo):
 
 class TestDeviceEncode:
     """index_jax_hi_lo must agree bit-for-bit with the host encode under
-    float64 (the CPU/x64 test platform; VERDICT round-2 item 1)."""
+    float64 (the CPU/x64 test platform)."""
 
     def test_xz2_parity_random(self, rng):
         import jax

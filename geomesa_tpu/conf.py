@@ -58,11 +58,12 @@ Properties:
                                 appended to the slow-query log, sampled
                                 or not (0 disables slow capture; with
                                 ``trace.sample=0`` that turns span
-                                recording off entirely)
-- ``trace.device.dir``          when set, sampled queries wrap their
-                                device launch in a ``jax.profiler``
-                                trace dumped to this directory
-                                (profiling.device_trace); "" = off
+                                recording off entirely). Neither key
+                                touches the declared stages
+                                (``tracing.STAGES``): their spans are
+                                always counted in
+                                ``geomesa_stage_seconds{stage}`` and
+                                ``geomesa_stage_cpu_seconds_total``
 - ``io.backoff.cap.ms``         cumulative cap on the transient-read
                                 backoff a single partition read may
                                 sleep (retries stop once spent)
@@ -421,12 +422,11 @@ _DEFS = {
     "store.chunk.grid": (64, int),
     "store.chunk.prune": (True, _parse_bool),
     "store.chunk.pushdown": (True, _parse_bool),
-    # per-request tracing (tracing.py): head-sampling probability, the
-    # slow-query always-capture threshold, and the optional jax.profiler
-    # device-trace dump directory for sampled launches
+    # per-request tracing (tracing.py): head-sampling probability and the
+    # slow-query always-capture threshold (the declared stages' counters
+    # ignore both)
     "trace.sample": (1.0, float),
     "trace.slow_ms": (500.0, float),
-    "trace.device.dir": ("", str),
     # device query scheduler defaults (sched/scheduler.py,
     # SchedConfig.from_props): admission queue bound, worker/inflight
     # cap, fusion window + width, default deadline (<= 0 = none) and the

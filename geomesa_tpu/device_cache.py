@@ -177,6 +177,15 @@ def _staging_query():
     )
 
 
+def _fetch(conv, x):
+    """``conv(x)``: the blocking host copy of a dispatched device result,
+    timed as the ``device.fetch`` stage (dispatch return to host value)."""
+    from geomesa_tpu.tracing import span
+
+    with span("device.fetch"):
+        return conv(x)
+
+
 def _z_planes_np(batch, sft: SimpleFeatureType):
     """(kind, planes, bins) via the HOST encode — the oracle the device
     staging path must match, and the fallback when the device encode is
@@ -1134,7 +1143,7 @@ class DeviceIndex:
                 auths=auths,
             )
             if outs is not None:
-                return int(outs["__count"])
+                return _fetch(int, outs["__count"])
             return int(self.mask(f, loose=loose, auths=auths).sum())
         if self._resolve_loose(loose):
             lb = self._loose_bounds(f)
@@ -1144,11 +1153,11 @@ class DeviceIndex:
                     # the bandwidth-champion path: Pallas dim-plane count,
                     # one dispatch, 12B/row
                     count_fn, _, kargs = self._dim_args(lb)
-                    return int(count_fn(*kargs))
+                    return _fetch(int, count_fn(*kargs))
                 m = self._z_mask_dev(lb)
                 if dv is not None:
                     m = m & dv
-                return int(m.sum())
+                return _fetch(int, m.sum())
         compiled, count_fn, _ = self._compiled_for(f)
         if not compiled.device_cols or count_fn is None:
             m = compiled.host_mask(self._host_rows())
@@ -1156,7 +1165,7 @@ class DeviceIndex:
             return int((m & hv).sum() if hv is not None else m.sum())
         if not compiled.fully_on_device:
             return len(self.query(query))
-        return int(count_fn(self._resident_subset(compiled)))
+        return _fetch(int, count_fn(self._resident_subset(compiled)))
 
     def loose_scan_kernel(self, query):
         """(count_fn, args) — the EXACT kernel + resident operands that
@@ -2126,7 +2135,7 @@ class DeviceIndex:
         if outs is None:  # filter not fully device-expressible
             seq.observe_batch(self.query(f, loose=loose, auths=auths))
             return seq
-        n_hits = int(outs["__count"])
+        n_hits = _fetch(int, outs["__count"])
         for i, (tag, s) in enumerate(device_parts):
             if tag == "count":
                 s.count += n_hits
@@ -2448,7 +2457,7 @@ class DeviceIndex:
             )
         if outs is None:
             return None
-        grid = np.asarray(outs["grid"])
+        grid = _fetch(np.asarray, outs["grid"])
         if kern is None:
             grid = grid[: height * width].reshape(height, width)
         return grid

@@ -33,7 +33,11 @@ class Counter(_Metric):
         super().__init__(name, help_, "counter")
 
     def inc(self, amount: float = 1.0, **labels) -> None:
-        key = self.labels(**labels)
+        self.inc_key(self.labels(**labels), amount)
+
+    def inc_key(self, key: tuple, amount: float = 1.0) -> None:
+        """``inc`` with the label key already built (``labels(...)``):
+        for hot paths whose label values come from a fixed set."""
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + amount
 
@@ -83,11 +87,18 @@ class Histogram(_Metric):
         self.buckets = tuple(sorted(buckets))
 
     def observe(self, v: float, *, exemplar=None, **labels) -> None:
-        key = self.labels(**labels)
+        self.observe_key(self.labels(**labels), v, exemplar)
+
+    def observe_key(self, key: tuple, v: float, exemplar=None) -> None:
+        """``observe`` with the label key already built (``labels(...)``):
+        for hot paths whose label values come from a fixed set."""
         with self._lock:
-            st = self._values.setdefault(
-                key, {"counts": [0] * (len(self.buckets) + 1), "sum": 0.0, "n": 0}
-            )
+            st = self._values.get(key)
+            if st is None:  # not setdefault: its default is built per call
+                st = self._values[key] = {
+                    "counts": [0] * (len(self.buckets) + 1), "sum": 0.0,
+                    "n": 0,
+                }
             # le-bucket: first bound >= v (cumulated at exposition time);
             # past the last bound lands in the trailing +Inf slot
             b = bisect_left(self.buckets, v)
@@ -488,6 +499,23 @@ traces_captured = REGISTRY.counter(
 slow_queries = REGISTRY.counter(
     "geomesa_slow_queries_total",
     "requests slower than trace.slow_ms (always-captured + slow-logged)",
+)
+
+# served-path stages (tracing.STAGES): every span of a declared stage
+# observes its wall time and adds its thread CPU time here, whether or
+# not the request's trace is recorded. The stage label takes only the
+# names of that fixed tuple
+stage_seconds = REGISTRY.histogram(
+    "geomesa_stage_seconds",
+    "wall time per declared serving/staging stage (stage)",
+    buckets=(
+        0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+        0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
+    ),
+)
+stage_cpu_seconds = REGISTRY.counter(
+    "geomesa_stage_cpu_seconds_total",
+    "thread CPU time spent inside live spans of a declared stage (stage)",
 )
 
 # serving SLO engine (slo.py): latency observations per endpoint/lane

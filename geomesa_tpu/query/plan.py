@@ -121,8 +121,7 @@ def _plan_query(
         guard_plan,
         interceptors_for,
     )
-
-    from geomesa_tpu.profiling import profile
+    from geomesa_tpu.tracing import span
 
     chain = interceptors_for(sft)
     query = apply_interceptors(chain, query, sft)
@@ -187,7 +186,7 @@ def _plan_query(
             else:
                 ranges = ks.ranges_for_values(bounds)
         else:
-            with profile("plan.scan_ranges"):
+            with span("plan.scan_ranges"):
                 ranges = ks.scan_ranges(
                     geoms, intervals, max_ranges, data_interval=data_interval
                 )

@@ -54,34 +54,12 @@ def _contiguous_runs(parts) -> "list[tuple[int, int]]":
 
 
 def run_query(built: BuiltIndex, plan: QueryPlan) -> QueryResult:
-    from geomesa_tpu.profiling import profile
     from geomesa_tpu.tracing import span
 
-    with profile("query.scan"), span("query.scan") as sp:
+    with span("query.scan") as sp:
         res = _run_query(built, plan)
         sp.set(scanned=res.scanned, hits=len(res))
         return res
-
-
-def _device_trace_ctx():
-    """The ``trace.device.dir`` hook: a SAMPLED request's device launch
-    is additionally wrapped in a ``jax.profiler`` dump (kernel timings,
-    HBM traffic) when the knob names a directory — the host-side trace
-    says WHICH launch was slow, the profiler dump says why."""
-    from contextlib import nullcontext
-
-    from geomesa_tpu.conf import sys_prop
-    from geomesa_tpu.tracing import current_trace
-
-    log_dir = str(sys_prop("trace.device.dir") or "")
-    if not log_dir:
-        return nullcontext()
-    t = current_trace()
-    if t is None or not t.sampled:
-        return nullcontext()
-    from geomesa_tpu.profiling import device_trace
-
-    return device_trace(log_dir)
 
 
 #: OOM-recovery recursion bound: halving a run more times than this
@@ -106,9 +84,7 @@ def _scan_run(built, compiled, jitted, start: int, stop: int,
         from geomesa_tpu import ledger
 
         t_stage = _time.perf_counter()
-        with span(
-            "device.launch", rows=int(stop - start)
-        ), _device_trace_ctx(), \
+        with span("device.launch", rows=int(stop - start)), \
                 ledger.compile_scope("store.scan"):
             fail_point("fail.device.launch")
             fail_point("fail.stage.oom")

@@ -381,7 +381,13 @@ class _Handler(BaseHTTPRequestHandler):
         self.wfile.write(body)
 
     def _json(self, code: int, doc) -> None:
-        self._send(code, json.dumps(doc).encode("utf-8"), "application/json")
+        """JSON answer; numpy arrays and scalars in ``doc`` turn into
+        lists and numbers inside the ``http.encode`` stage."""
+        from geomesa_tpu.tracing import span
+
+        with span("http.encode", fmt="json"):
+            body = json.dumps(doc, default=_tolist).encode("utf-8")
+        self._send(code, body, "application/json")
 
     def _observe_encode(self, fmt: str, enc_s: float, write_s: float,
                         total: int, rows, batches: int) -> None:
@@ -654,7 +660,7 @@ class _Handler(BaseHTTPRequestHandler):
             self._cost = None
             return self._dispatch_safe(url, parts, q)
         from geomesa_tpu import ledger, resilience
-        from geomesa_tpu.tracing import TRACER
+        from geomesa_tpu.tracing import TRACER, span
 
         tenant = q.get("tenant") or (
             str(self.client_address[0]) if self.client_address else ""
@@ -667,8 +673,10 @@ class _Handler(BaseHTTPRequestHandler):
         # response/audit stamping reads it back. The cost collector
         # rides along too — it is finalized AFTER the trace completes
         # (the span tree is whole at that point) and folded into the
-        # process ledger + the SLO engine's latency windows.
-        with TRACER.trace(
+        # process ledger + the SLO engine's latency windows. The
+        # http.request stage wraps the whole traced request, to its
+        # last byte written; the trace's root span is its tree node.
+        with span("http.request"), TRACER.trace(
             f"GET {url.path}",
             trace_id=self.headers.get("X-Request-Id"),
             attrs={"path": url.path, "query": url.query[:512]},
@@ -781,12 +789,12 @@ class _Handler(BaseHTTPRequestHandler):
         # sub-ms host work must not queue behind device scans)
         q.setdefault("lane", "ingest")
         from geomesa_tpu import ledger, resilience
-        from geomesa_tpu.tracing import TRACER
+        from geomesa_tpu.tracing import TRACER, span
 
         tenant = q.get("tenant") or (
             str(self.client_address[0]) if self.client_address else ""
         )
-        with TRACER.trace(
+        with span("http.request"), TRACER.trace(
             f"POST {url.path}",
             trace_id=self.headers.get("X-Request-Id"),
             attrs={"path": url.path, "bytes": len(body)},
@@ -2206,7 +2214,7 @@ class _Handler(BaseHTTPRequestHandler):
                 "bbox": list(bbox),
                 "width": width,
                 "height": height,
-                "counts": grid.tolist(),
+                "counts": grid,  # to a list inside http.encode
             },
         )
 
@@ -2219,6 +2227,15 @@ _KNOWN_ENDPOINTS = frozenset({
     "knn", "tube", "proximity", "capabilities", "append", "wal",
     "subscribe",
 })
+
+
+def _tolist(o):
+    """``json.dumps`` default: numpy arrays and scalars as plain lists
+    and numbers (the body is what ``o.tolist()`` would have given)."""
+    if hasattr(o, "tolist"):
+        return o.tolist()
+    raise TypeError(f"Object of type {type(o).__name__} is not JSON "
+                    "serializable")
 
 
 def _cost_endpoint(parts: list) -> str:

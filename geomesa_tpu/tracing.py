@@ -4,11 +4,12 @@ path.
 Ref role: geomesa-utils MethodProfiling + the ``explain`` output are the
 reference's de-facto query profiler [UNVERIFIED - empty reference
 mount]; PAPER.md section 5 maps them to ``jax.profiler`` traces plus
-host timers. :mod:`geomesa_tpu.profiling` keeps the AGGREGATE face of
-that mapping (wall time per label, process-wide); this module is the
-PER-REQUEST face: when one query is slow, its trace says where the time
-went — which fused launch it rode, how long it waited in the scheduler
-queue, which partition reads it sat behind.
+host timers. This module is both faces of that mapping. PER REQUEST:
+when one query is slow, its trace says where the time went — which
+fused launch it rode, how long it waited in the scheduler queue, which
+partition reads it sat behind. AGGREGATE: a span whose name is one of
+the declared :data:`STAGES` is also counted process-wide and annotated
+on the profiler's clock (below).
 
 Model:
 
@@ -32,6 +33,15 @@ Model:
   this around its work items; the scheduler does it around execution).
   Retroactive spans (queue wait, a shared fused launch fanned out to
   every rider's trace) use :func:`record_span` with an explicit start.
+- Declared stages (:data:`STAGES`, the served path's phases) are
+  counted whatever ``trace.sample``/``trace.slow_ms`` say: a live span
+  observes its wall time in ``geomesa_stage_seconds{stage}`` and adds
+  its thread CPU time to ``geomesa_stage_cpu_seconds_total{stage}``; a
+  retroactive one observes the wall histogram only. When JAX is already
+  imported, a live stage span also enters
+  ``jax.profiler.TraceAnnotation(<stage>)`` on its thread, so any
+  profiler trace carries the stages on its host planes, on the device
+  ops' clock. In the tree a stage span nests like any other.
 
 Export: ``Trace.to_dict()`` is the ``/debug/traces/<id>`` JSON;
 ``Trace.to_perfetto()`` emits Chrome-trace/Perfetto JSON (load in
@@ -45,12 +55,14 @@ import contextvars
 import json
 import os
 import random
+import sys
 import threading
 import time
 import uuid
 from collections import OrderedDict
 from contextlib import contextmanager
 
+from geomesa_tpu import metrics
 from geomesa_tpu.locking import checked_lock
 
 __all__ = [
@@ -58,6 +70,7 @@ __all__ = [
     "Trace",
     "Tracer",
     "TRACER",
+    "STAGES",
     "span",
     "record_span",
     "capture",
@@ -71,6 +84,32 @@ __all__ = [
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "geomesa_tpu_span", default=None
 )
+
+#: the declared stages of the served path: the only span names that are
+#: counted (the bounded ``stage`` label) and annotated on the profiler's
+#: clock. ``http.*`` run on the handler thread, ``sched.execute`` on a
+#: scheduler worker, ``device.fetch`` is the blocking host copy of a
+#: resident count/density result. Declare a name here only for a reader
+#: that uses it: each pays its counters on every request
+STAGES = (
+    "http.request",
+    "http.encode",
+    "sched.execute",
+    "device.fetch",
+)
+#: each stage's label key, built once: spans of these names run on
+#: every request
+_STAGE_KEYS = {s: metrics.stage_seconds.labels(stage=s) for s in STAGES}
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` when JAX is already
+    imported, else None — this module never imports JAX itself."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None) if jax is not None else None
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name)
 
 
 def _new_trace_id() -> str:
@@ -311,8 +350,6 @@ class Tracer:
         if not t.recording or not (t.sampled or t.slow):
             return
         try:
-            from geomesa_tpu import metrics
-
             metrics.traces_captured.inc()
             if t.slow:
                 metrics.slow_queries.inc()
@@ -421,25 +458,50 @@ def span(name: str, parent=None, **attrs):
     """``with span("store.read", pid=3) as sp:`` — a timed child of the
     current span (or of ``parent``, for explicit cross-thread
     parenting). No active trace -> a shared no-op span; ``sp.set(...)``
-    always works."""
+    always works. A declared stage (:data:`STAGES`) is counted and
+    annotated on the profiler's clock with or without a trace."""
     p = parent if parent is not None else _current.get()
-    if p is None or p is _NOOP:
+    recorded = p is not None and p is not _NOOP
+    key = _STAGE_KEYS.get(name)
+    if not recorded and key is None:
         yield _NOOP
         return
-    sp = p.trace.begin_span(name, p, attrs)
-    token = _current.set(sp)
+    sp, token = _NOOP, None
+    if recorded:
+        sp = p.trace.begin_span(name, p, attrs)
+        token = _current.set(sp)
+    ann = _annotation(name) if key is not None else None
+    if ann is not None:
+        ann.__enter__()
     t0 = time.perf_counter()
+    c0 = time.thread_time()
     try:
         yield sp
     finally:
-        sp.dur_s = time.perf_counter() - t0
-        _current.reset(token)
+        # the CPU reads nest inside the wall reads; the two clocks are
+        # separate sources, so the nesting alone cannot rule out a few
+        # ns of skew: a thread's CPU time never exceeds its wall time
+        cpu = time.thread_time() - c0
+        dur = time.perf_counter() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if token is not None:
+            sp.dur_s = dur
+            _current.reset(token)
+        if key is not None:
+            metrics.stage_seconds.observe_key(key, dur)
+            metrics.stage_cpu_seconds.inc_key(key, min(cpu, dur))
 
 
 def record_span(parent, name: str, start_perf: float, dur_s: float, **attrs):
     """Attach an already-timed span under ``parent`` (a captured span):
     queue waits and shared fused launches are timed by the scheduler and
-    fanned out to every rider's trace after the fact."""
+    fanned out to every rider's trace after the fact. A declared stage
+    observes its wall time with or without a trace (no CPU time: the
+    work ran elsewhere)."""
+    key = _STAGE_KEYS.get(name)
+    if key is not None:
+        metrics.stage_seconds.observe_key(key, dur_s)
     if parent is None or parent is _NOOP:
         return None
     return parent.trace.add_finished(name, parent, start_perf, dur_s, attrs)

@@ -324,6 +324,86 @@ def test_resident_density_fused(resident_url):
     assert np.array(doc["counts"]).sum() > 0
 
 
+def _stage_counts(url) -> dict:
+    """{stage: observations} of ``geomesa_stage_seconds`` on /metrics,
+    plus the store's per-file decodes under ``"io.decode"``."""
+    _, _, body = _get(f"{url}/metrics")
+    out = {}
+    for line in body.decode().splitlines():
+        if line.startswith('geomesa_stage_seconds_count{stage="'):
+            out[line.split('"')[1]] = float(line.rsplit(" ", 1)[1])
+        elif line.startswith("geomesa_io_decode_seconds_count "):
+            out["io.decode"] = float(line.rsplit(" ", 1)[1])
+    return out
+
+
+def test_resident_requests_move_each_stage_once(tmp_path):
+    """One resident /count and one /density through the scheduler each
+    move http.request, http.encode, sched.execute and device.fetch by
+    exactly one; staging an FS store counts its decodes; the stage
+    label carries only the declared names."""
+    import time
+
+    from geomesa_tpu.store.fs import FileSystemDataStore
+    from geomesa_tpu.tracing import STAGES
+
+    ds = FileSystemDataStore(str(tmp_path), partition_size=1024)
+    ds.create_schema("gdelt", SPEC)
+    n = 4000
+    rng = np.random.default_rng(23)
+    t0 = parse_instant("2020-01-01T00:00:00")
+    ds.write(
+        "gdelt",
+        {
+            "name": rng.choice(["a", "b"], n),
+            "dtg": t0 + rng.integers(0, 10**8, n),
+            "geom": np.stack(
+                [rng.uniform(-20, 20, n), rng.uniform(-20, 20, n)], axis=1
+            ),
+        },
+        fids=np.arange(n),
+    )
+    ds.flush("gdelt")
+    server, _ = serve_background(ds, resident=True, sched=True)
+    host, port = server.server_address[:2]
+    url = f"http://{host}:{port}"
+    try:
+        staged0 = _stage_counts(url)
+        _get(f"{url}/count/gdelt?cql=INCLUDE")  # the first query stages
+        time.sleep(0.2)
+        staged = _stage_counts(url)
+        assert staged.get("io.decode", 0) > staged0.get("io.decode", 0)
+        cql = urllib.request.quote(
+            "BBOX(geom, -5, -5, 5, 5) AND "
+            "dtg DURING 2020-01-01T00:00:00Z/2020-01-01T20:00:00Z"
+        )
+        watched = ("http.request", "http.encode", "sched.execute",
+                   "device.fetch")
+        for path in (
+            f"/count/gdelt?cql={cql}",
+            f"/density/gdelt?bbox=-5,-5,5,5&width=16&height=8&cql={cql}",
+        ):
+            before = _stage_counts(url)
+            status, _, body = _get(url + path)
+            assert status == 200 and json.loads(body)
+            # the handler observes http.request after its last byte:
+            # poll until it lands, then settle and read exactly once
+            deadline = time.time() + 10
+            while time.time() < deadline:
+                after = _stage_counts(url)
+                if after.get("http.request", 0) > before.get(
+                        "http.request", 0):
+                    break
+                time.sleep(0.01)
+            time.sleep(0.2)
+            after = _stage_counts(url)
+            moved = {s: after.get(s, 0) - before.get(s, 0) for s in watched}
+            assert moved == dict.fromkeys(watched, 1), (path, moved)
+        assert set(_stage_counts(url)) - {"io.decode"} <= set(STAGES)
+    finally:
+        server.shutdown()
+
+
 def test_server_auths_param_resident_and_store():
     """auths=A,B serves labeled rows from the resident fast path; absent
     auths fail closed. Store-path (non-resident) behavior is identical."""

@@ -487,6 +487,154 @@ class TestServerEndToEnd:
         assert "agg.pushdown" in out and "coverage" in out
 
 
+def _stage(stage: str) -> "tuple[int, float, float]":
+    """(count, wall sum, CPU sum) of one declared stage so far."""
+    from geomesa_tpu import metrics
+
+    st = metrics.stage_seconds.stats(stage=stage)
+    return st["n"], st["sum"], metrics.stage_cpu_seconds.value(stage=stage)
+
+
+class TestDeclaredStages:
+    """Spans named in tracing.STAGES are counted whatever the sampling
+    says, and annotated on the profiler's clock."""
+
+    @pytest.mark.parametrize("sample,slow_ms", [(0.0, 0.0), (1.0, 500.0)])
+    def test_declared_span_counts_whatever_the_sampling(self, sample,
+                                                        slow_ms):
+        tr = Tracer()
+        n0, s0, _ = _stage("device.fetch")
+        with prop_override("trace.sample", sample), \
+                prop_override("trace.slow_ms", slow_ms):
+            with tr.trace("req") as t:
+                with span("device.fetch") as sp:
+                    time.sleep(0.003)
+                    sp.set(rows=1)
+            # outside any trace too (a staging or worker thread)
+            with span("device.fetch"):
+                pass
+        n1, s1, _ = _stage("device.fetch")
+        assert n1 - n0 == 2
+        assert s1 - s0 >= 0.003
+        kept = tr.get(t.trace_id)
+        if sample:
+            # the tree is as for any span: the stage nests under the root
+            root = kept.to_dict()["spans"]
+            assert [c["name"] for c in root["children"]] == ["device.fetch"]
+            assert root["children"][0]["attrs"]["rows"] == 1
+        else:
+            assert kept is None and not t.recording
+
+    def test_undeclared_name_leaves_the_histogram_untouched(self):
+        from geomesa_tpu import metrics
+        from geomesa_tpu.tracing import STAGES
+
+        before = metrics.REGISTRY.prometheus_text()
+        with prop_override("trace.sample", 1.0):
+            with Tracer().trace("req"):
+                with span("query.plan"):
+                    pass
+            with span("not.a.stage"):
+                pass
+        after = metrics.REGISTRY.prometheus_text()
+
+        def stage_lines(text):
+            return sorted(
+                line for line in text.splitlines()
+                if line.startswith("geomesa_stage_")
+            )
+
+        assert 'stage="query.plan"' not in after
+        assert 'stage="not.a.stage"' not in after
+        assert stage_lines(before) == stage_lines(after)
+        labels = {
+            line.split('stage="')[1].split('"')[0]
+            for line in after.splitlines()
+            if line.startswith("geomesa_stage_") and 'stage="' in line
+        }
+        assert labels <= set(STAGES)
+
+    def test_cpu_never_exceeds_wall(self):
+        n0, s0, c0 = _stage("sched.execute")
+        for busy in (0, 2000, 200_000):
+            with span("sched.execute"):
+                sum(range(busy))
+        with span("sched.execute"):
+            time.sleep(0.02)  # wall without CPU
+        n1, s1, c1 = _stage("sched.execute")
+        assert n1 - n0 == 4
+        assert 0.0 <= c1 - c0 <= s1 - s0
+        # the sleep put most of the wall outside the thread's CPU time
+        assert c1 - c0 < s1 - s0 - 0.015
+
+    def test_retroactive_stage_observes_wall_only(self):
+        tr = Tracer()
+        n0, s0, c0 = _stage("http.encode")
+        with prop_override("trace.sample", 1.0):
+            with tr.trace("req") as t:
+                record_span(capture(), "http.encode",
+                            time.perf_counter(), 0.25)
+        record_span(None, "http.encode", time.perf_counter(), 0.5)
+        n1, s1, c1 = _stage("http.encode")
+        assert n1 - n0 == 2 and s1 - s0 == pytest.approx(0.75)
+        assert c1 == c0
+        root = tr.get(t.trace_id).to_dict()["spans"]
+        assert root["children"][0]["dur_ms"] == 250.0
+
+    def test_concurrent_stage_spans_lose_no_count(self):
+        import os
+        import sys
+
+        n0, _, _ = _stage("http.request")
+        threads, per = 2 * (os.cpu_count() or 4), 500
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            def worker():
+                for _ in range(per):
+                    with span("http.request"):
+                        pass
+
+            ts = [threading.Thread(target=worker) for _ in range(threads)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in ts)
+        finally:
+            sys.setswitchinterval(switch)
+        n1, _, _ = _stage("http.request")
+        assert n1 - n0 == threads * per
+
+    def test_profiler_trace_holds_the_stage(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with span("sched.execute"):
+                with span("device.fetch"):
+                    int(jax.numpy.arange(8).sum())
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)[0]
+        host = [
+            (ev.name, ev.start_ns, ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for ev in line.events
+        ]
+        by_name = {name: (a, d) for name, a, d in host}
+        assert {"sched.execute", "device.fetch"} <= set(by_name)
+        outer, inner = by_name["sched.execute"], by_name["device.fetch"]
+        assert outer[0] <= inner[0]
+        assert inner[0] + inner[1] <= outer[0] + outer[1]
+
+
 class TestMetricsRegressions:
     def test_label_values_escaped(self):
         from geomesa_tpu.metrics import MetricsRegistry

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from geomesa_tpu.features.batch import FeatureBatch
+from geomesa_tpu.features.batch import FeatureBatch, decode_strings
 from geomesa_tpu.features.sft import SimpleFeatureType
 from geomesa_tpu.geom.base import (
     LineString,
@@ -269,6 +269,9 @@ def arrow_to_batch(rb, sft: "SimpleFeatureType | None" = None) -> FeatureBatch:
     cols: dict = {}
     for attr in sft.attributes:
         arr = rb.column(rb.schema.get_field_index(attr.name))
+        if attr.type_name == "String":
+            cols[attr.name] = decode_strings(arr)
+            continue
         if hasattr(arr, "combine_chunks"):
             arr = arr.combine_chunks()
         if attr.is_geometry:
@@ -280,10 +283,6 @@ def arrow_to_batch(rb, sft: "SimpleFeatureType | None" = None) -> FeatureBatch:
                 .astype("datetime64[ms]")
                 .astype(np.int64)
             )
-        elif attr.type_name == "String":
-            if hasattr(arr, "dictionary_decode"):
-                arr = arr.dictionary_decode()
-            cols[attr.name] = np.array(arr.to_pylist(), dtype=object)
         elif attr.column_dtype is not None:
             cols[attr.name] = arr.to_numpy(zero_copy_only=False).astype(
                 attr.column_dtype
@@ -297,5 +296,5 @@ def arrow_to_batch(rb, sft: "SimpleFeatureType | None" = None) -> FeatureBatch:
 
     vidx = rb.schema.get_field_index(VIS_COLUMN)
     if vidx >= 0:
-        batch = batch.with_visibility(rb.column(vidx).to_pylist())
+        batch = batch.with_visibility(decode_strings(rb.column(vidx)))
     return batch

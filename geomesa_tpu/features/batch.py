@@ -22,6 +22,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from geomesa_tpu import metrics
 from geomesa_tpu.features.sft import SimpleFeatureType
 from geomesa_tpu.geom import Geometry, Point, parse_wkt, to_wkt
 
@@ -252,20 +253,68 @@ class FeatureBatch:
                 if attr.column_dtype is not None:
                     cols[attr.name] = arr.to_numpy().astype(attr.column_dtype)
                 else:
-                    cols[attr.name] = np.array(arr.to_pylist(), dtype=object)
-        fids = (
-            table.column("__fid__").to_numpy(zero_copy_only=False)
-            if "__fid__" in names
-            else None
-        )
+                    cols[attr.name] = decode_strings(arr)
+        fids = None
+        if "__fid__" in names:
+            fids = table.column("__fid__")
+            fids = (
+                decode_strings(fids)
+                if is_string_type(fids.type)
+                else fids.to_numpy(zero_copy_only=False)
+            )
         batch = FeatureBatch.from_columns(sft, cols, fids)
         from geomesa_tpu.security import VIS_COLUMN
 
         if VIS_COLUMN in names:
             batch = batch.with_visibility(
-                table.column(VIS_COLUMN).to_pylist()
+                decode_strings(table.column(VIS_COLUMN))
             )
         return batch
+
+
+def is_string_type(t) -> bool:
+    """Arrow ``string``/``large_string``, plain or dictionary-encoded."""
+    import pyarrow as pa
+
+    if pa.types.is_dictionary(t):
+        t = t.value_type
+    return pa.types.is_string(t) or pa.types.is_large_string(t)
+
+
+def decode_strings(col) -> np.ndarray:
+    """Arrow column (Array or ChunkedArray) -> object array, equal element
+    for element to ``np.array(col.to_pylist(), dtype=object)``: ``str``
+    values, ``None`` for nulls.
+
+    String columns never build an Arrow scalar per value. A dictionary
+    chunk converts its dictionary once and gathers it by the indices
+    (nulls index an appended ``None``), so every row holding one distinct
+    value of the chunk shares one ``str``; a plain column takes Arrow's
+    vectorized conversion. (``to_numpy`` on a dictionary column does not
+    keep its nulls, hence the gather.) Other types keep ``to_pylist``."""
+    import pyarrow as pa
+
+    t = col.type
+    if not is_string_type(t):
+        return np.array(col.to_pylist(), dtype=object)
+    if not pa.types.is_dictionary(t):
+        out = col.to_numpy(zero_copy_only=False)
+        metrics.io_decode_string_values.inc(len(out), path="plain")
+        return out
+    chunks = col.chunks if isinstance(col, pa.ChunkedArray) else [col]
+    out = np.empty(len(col), dtype=object)
+    off = 0
+    for ch in chunks:
+        d = len(ch.dictionary)
+        vals = np.empty(d + 1, dtype=object)
+        vals[:d] = ch.dictionary.to_numpy(zero_copy_only=False)
+        idx = ch.indices
+        if idx.null_count:  # widened first: d may not fit an int8 index
+            idx = idx.cast(pa.int64()).fill_null(d)
+        out[off:off + len(ch)] = vals[idx.to_numpy()]
+        off += len(ch)
+    metrics.io_decode_string_values.inc(len(out), path="dictionary")
+    return out
 
 
 def _coerce_geometry(vals, is_point: bool) -> np.ndarray:

@@ -298,6 +298,11 @@ io_decode_seconds = REGISTRY.histogram(
     "geomesa_io_decode_seconds",
     "Arrow-to-FeatureBatch decode time (per file)",
 )
+io_decode_string_values = REGISTRY.counter(
+    "geomesa_io_decode_string_values_total",
+    "Arrow String values decoded into host object arrays, by path "
+    "(dictionary gather or plain conversion)",
+)
 io_stage_seconds = REGISTRY.histogram(
     "geomesa_io_stage_seconds",
     "host column staging time (per slab chunk)",

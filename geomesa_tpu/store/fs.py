@@ -45,7 +45,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from geomesa_tpu.features.batch import FeatureBatch
+from geomesa_tpu.features.batch import FeatureBatch, is_string_type
 from geomesa_tpu.features.sft import SimpleFeatureType
 from geomesa_tpu.filter import ast
 from geomesa_tpu.index.api import BuiltIndex, KeyRange, PartitionMeta
@@ -149,11 +149,25 @@ def _read_table(path: str, encoding: str, row_groups=None):
         import pyarrow.orc as orc
 
         return orc.read_table(path)
+    return _read_parquet(path, row_groups)
+
+
+def _read_parquet(source, row_groups=None):
+    """Parquet path or buffer -> Arrow table whose string columns stay
+    dictionary-encoded: the reader hands over the dictionary pages
+    :func:`_write_table` made instead of expanding them value by value,
+    and :func:`FeatureBatch.from_arrow` gathers each chunk's dictionary
+    once. (The footer read for the schema is a fraction of a ms.)"""
     import pyarrow.parquet as pq
 
+    strings = [
+        f.name for f in pq.read_schema(source) if is_string_type(f.type)
+    ] or None
     if row_groups is None:
-        return pq.read_table(path)
-    return pq.ParquetFile(path).read_row_groups(list(row_groups))
+        return pq.read_table(source, read_dictionary=strings)
+    return pq.ParquetFile(source, read_dictionary=strings).read_row_groups(
+        list(row_groups)
+    )
 
 
 def _encode_table(table, encoding: str, row_group_rows=None) -> bytes:
@@ -198,16 +212,11 @@ def _parse_table(data: bytes, encoding: str, row_groups=None):
     only the surviving row groups pay the decompress/decode)."""
     import pyarrow as pa
 
-    buf = pa.BufferReader(pa.py_buffer(data))
     if encoding == "orc":
         import pyarrow.orc as orc
 
-        return orc.read_table(buf)
-    import pyarrow.parquet as pq
-
-    if row_groups is None:
-        return pq.read_table(buf)
-    return pq.ParquetFile(buf).read_row_groups(list(row_groups))
+        return orc.read_table(pa.BufferReader(pa.py_buffer(data)))
+    return _read_parquet(pa.py_buffer(data), row_groups)
 
 
 def _row_group_nbytes(data: bytes) -> "list[int]":

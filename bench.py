@@ -86,6 +86,12 @@ def _measure(chain, inputs, args, k: int, n: int, bytes_per_row: int,
     }
 
 
+def _hits(count_fn):
+    """A Pallas count ([hits, blocks tested, blocks skipped]) as a
+    jittable count of hits."""
+    return lambda c: count_fn(c)[0]
+
+
 def _chain(scan_fn, k):
     """One jitted dispatch running ``scan_fn`` k times: the barrier ties
     every input to the loop carry, so the loop body cannot be hoisted or
@@ -163,7 +169,7 @@ def bench_filter(args) -> dict:
     if args.engine == "pallas":
         scan = compiled.pallas_scan()
         assert scan is not None, "filter not pallas-tileable"
-        scan_fn = scan[0]
+        scan_fn = _hits(scan[0])
     else:
         def scan_fn(c):
             return compiled.device_fn(c).sum()
@@ -199,7 +205,7 @@ def bench_filter(args) -> dict:
                 other = jax.jit(lambda c: compiled.device_fn(c).sum())
                 oracle = "independent XLA-engine count"
             else:
-                other = jax.jit(compiled.pallas_scan()[0])
+                other = jax.jit(_hits(compiled.pallas_scan()[0]))
                 oracle = "independent Pallas-engine count"
             expect = int(other(cols))
         assert hits == expect, f"device {hits} != oracle {expect}"
@@ -469,7 +475,7 @@ def _scan_metric(args, cols, ecql, label, engine=None):
     if engine == "pallas":
         scan = compiled.pallas_scan()
         if scan is not None:
-            scan_fn = scan[0]
+            scan_fn = _hits(scan[0])
     if scan_fn is None:
         def scan_fn(c):
             return compiled.device_fn(c).sum()

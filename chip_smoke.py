@@ -295,8 +295,8 @@ def timed(fn):
 
 def engines(di, cql: str) -> dict:
     """Which device engine serves ``cql`` on the resident index ``di``:
-    the exact filter's kernel (Pallas tiles or XLA-fused jnp, set by
-    ``CompiledFilter.jitted_scan``) and the loose key-plane engine."""
+    the exact filter's kernel (Pallas tiles, XLA-fused jnp or the host,
+    as the index recorded it) and the loose key-plane engine."""
     from geomesa_tpu.filter.ecql import parse_ecql
 
     f = parse_ecql(cql)
@@ -304,8 +304,8 @@ def engines(di, cql: str) -> dict:
     if exact is None:
         eng = "not compiled"
     else:
-        eng = getattr(exact[0], "scan_engine", "host")
-        if eng == "pallas" and not di._pallas_tiles:
+        eng = exact[3]
+        if eng == "xla" and not di._pallas_tiles:
             eng = "xla (mesh-partitioned)"
     lb = di._loose_bounds(f)
     loose = "none" if lb is None else (

@@ -55,6 +55,8 @@ Z_NX, Z_NY, Z_BT = "__znx", "__zny", "__zbt"
 # serving: each row carries the id of its label expression in a small
 # vocabulary; a per-request auth table gathers to a bool mask on device)
 VIS_ID = "__visid"
+# CQL strings whose parsed filter a DeviceIndex remembers (``_keyed``)
+_TEXTS_MAX = 4096
 
 
 class _VisOverflow(Exception):
@@ -186,6 +188,11 @@ def _fetch(conv, x):
         return conv(x)
 
 
+def _ready(value):
+    """The fetch of a count already on the host (``_launch_count``)."""
+    return lambda: value
+
+
 def _z_planes_np(batch, sft: SimpleFeatureType):
     """(kind, planes, bins) via the HOST encode — the oracle the device
     staging path must match, and the fallback when the device encode is
@@ -288,6 +295,7 @@ class DeviceIndex:
         self._host_batch = None
         self._cols = None
         self._compiled: dict = {}
+        self._texts: dict = {}  # CQL text -> (filter, _compiled key)
         self._z_jit = None
         self._z_encode_jit = None
         self._dim_encode_jit = None
@@ -1031,7 +1039,10 @@ class DeviceIndex:
         return len(self._host_batch)
 
     def _make_scan_fns(self, compiled):
-        """(count_fn, mask_fn) taking the resident column subset.
+        """(count_fn, mask_fn, engine) over the resident column subset:
+        count_fn launches the count and returns the zero-arg fetch of its
+        host value, mask_fn returns the device mask, and
+        ``engine`` names the scan both dispatch ("pallas" or "xla").
 
         When a device validity plane exists (padded buffers: streaming
         deltas, mesh shards) it is ANDed into the fused scan — padding
@@ -1041,45 +1052,77 @@ class DeviceIndex:
         valid-aware jit from then on. The Pallas tile kernels take the
         plane as one more input; a mesh index keeps the XLA-fused scan,
         which jit partitions across the shards (a Pallas call is not
-        SPMD-partitionable)."""
+        SPMD-partitionable). A count on the polygon kernel moves the
+        point-in-polygon block and edge-test counters."""
         import jax
-        import jax.numpy as jnp
+
+        from geomesa_tpu import metrics
+        from geomesa_tpu.filter.compile import has_polygon, xla_counts
 
         plain_count, plain_mask = compiled.jitted_scan()
+        engine = compiled.scan_engine
+        if engine == "pallas" and not self._pallas_tiles:
+            engine = "xla"
+            plain_mask = jax.jit(compiled.device_fn)
+            plain_count = jax.jit(lambda c: xla_counts(compiled.device_fn(c)))
         if self._device_valid() is None and type(self) is DeviceIndex:
             # the base cache never pads: skip the per-call dispatch
-            return plain_count, plain_mask
-        if compiled.scan_engine == "pallas" and self._pallas_tiles:
-            count_jit, mask_jit = plain_count, plain_mask
+            dispatch, mask_fn = plain_count, plain_mask
         else:
-            mask_jit = jax.jit(
-                lambda cols, valid: compiled.device_fn(cols) & valid
-            )
-            count_jit = jax.jit(
-                lambda cols, valid: jnp.sum(compiled.device_fn(cols) & valid)
-            )
+            if engine == "pallas":
+                count_jit, mask_jit = plain_count, plain_mask
+            else:
+                mask_jit = jax.jit(
+                    lambda cols, valid: compiled.device_fn(cols) & valid
+                )
+                count_jit = jax.jit(
+                    lambda cols, valid: xla_counts(
+                        compiled.device_fn(cols) & valid)
+                )
+
+            def dispatch(cols):
+                dv = self._device_valid()
+                return count_jit(cols, dv) if dv is not None else \
+                    plain_count(cols)
+
+            def mask_fn(cols):
+                dv = self._device_valid()
+                return mask_jit(cols, dv) if dv is not None else plain_mask(
+                    cols
+                )
+
+        edge_tests = 0  # per tested block
+        if engine == "pallas" and has_polygon(compiled.device_part):
+            from geomesa_tpu.ops import pallas_scan as ps
+
+            edge_tests = ps.SKIP_ROWS * ps.LANES * ps.padded_edges(
+                compiled.device_part)
 
         def count_fn(cols):
-            dv = self._device_valid()
-            return count_jit(cols, dv) if dv is not None else plain_count(
-                cols
-            )
+            out = dispatch(cols)
 
-        def mask_fn(cols):
-            dv = self._device_valid()
-            return mask_jit(cols, dv) if dv is not None else plain_mask(
-                cols
-            )
+            def fetch() -> int:
+                hits, tested, skipped = _fetch(np.asarray, out).tolist()
+                if edge_tests:
+                    metrics.pip_blocks.inc_key(metrics.PIP_TESTED, tested)
+                    metrics.pip_blocks.inc_key(metrics.PIP_SKIPPED, skipped)
+                    metrics.pip_edge_tests.inc(tested * edge_tests)
+                return hits
 
-        return count_fn, mask_fn
+            return fetch
+
+        return count_fn, mask_fn, engine
 
     # -- queries -----------------------------------------------------------
 
-    def _compiled_for(self, query):
+    def _compiled_for(self, query, key=None):
+        """The ``_compiled`` entry of a filter, keyed by its ``repr``
+        (``key``, when the caller already holds it from :meth:`_keyed`)."""
         from geomesa_tpu.filter.compile import compile_filter
 
         f = self._parse(query)
-        key = repr(f)
+        if key is None:
+            key = repr(f)
         if key not in self._compiled:
             compiled = compile_filter(f, self.sft)
             missing = [c for c in compiled.device_cols if c not in self._cols]
@@ -1095,10 +1138,10 @@ class DeviceIndex:
                     f"for the device path)",
                     stacklevel=3,
                 )
-                self._compiled[key] = (compiled, None, None)
+                self._compiled[key] = (compiled, None, None, "host")
             else:
-                count_fn, mask_fn = self._make_scan_fns(compiled)
-                self._compiled[key] = (compiled, count_fn, mask_fn)
+                self._compiled[key] = (compiled, *self._make_scan_fns(
+                    compiled))
         return self._compiled[key]
 
     def _resident_subset(self, compiled) -> dict:
@@ -1115,9 +1158,34 @@ class DeviceIndex:
                 "DeviceIndex takes a CQL string or filter AST; pass "
                 "auths= explicitly (Query hints are store-path plumbing)"
             )
-        return parse_ecql(query) if isinstance(query, str) else query
+        if not isinstance(query, str):
+            return query
+        return self._keyed(query)[0]
 
-    @_scan_scoped
+    def _keyed(self, query):
+        """(filter, ``_compiled`` key) of a CQL string; an AST comes back
+        with key None (:meth:`_compiled_for` prints it). A string's pair
+        is remembered (filters are immutable and the parse reads no index
+        state), so a repeated request skips both the parse and the key's
+        ``repr`` -- each walks every vertex of a polygon literal. The memo
+        holds at most ``_TEXTS_MAX`` strings and starts over when full.
+        The ``cql.parse`` stage times the lookup, and the parse on a
+        miss."""
+        from geomesa_tpu.filter.ecql import parse_ecql
+        from geomesa_tpu.tracing import span
+
+        if not isinstance(query, str):
+            return self._parse(query), None
+        with span("cql.parse"):
+            hit = self._texts.get(query)
+            if hit is None:
+                f = parse_ecql(query)
+                hit = (f, repr(f))
+                if len(self._texts) >= _TEXTS_MAX:
+                    self._texts.clear()
+                self._texts[query] = hit
+            return hit
+
     def count(
         self, query, loose: "bool | None" = None, auths=None
     ) -> int:
@@ -1127,24 +1195,32 @@ class DeviceIndex:
         cell granularity from the resident key planes. ``auths`` applies
         per-request row security against the staged label-id plane
         (None/() hides labeled rows — fail closed)."""
+        return self._launch_count(query, loose=loose, auths=auths)()
+
+    @_scan_scoped
+    def _launch_count(self, query, loose=None, auths=None):
+        """:meth:`count` up to its device launch: returns the zero-arg
+        fetch of the count. The launch reads the resident planes; the
+        fetch only waits for the launched work, so an index that
+        serializes its scans holds its lock for the launch alone."""
         import jax.numpy as jnp
 
         from geomesa_tpu.failpoints import fail_point
 
         fail_point("fail.device.launch")  # chaos: resident count launch
-        f = self._parse(query)
+        f, key = self._keyed(query)
         if VIS_ID in (self._cols or {}):
             # labeled data: the auth table must AND into the device mask
             if self._staged_len() == 0:
-                return 0
+                return _ready(0)
             outs = self._fused_agg(
                 f, loose, ("count",),
                 lambda cols, m: {"__count": jnp.sum(m, dtype=jnp.int32)},
                 auths=auths,
             )
             if outs is not None:
-                return _fetch(int, outs["__count"])
-            return int(self.mask(f, loose=loose, auths=auths).sum())
+                return partial(_fetch, int, outs["__count"])
+            return _ready(int(self.mask(f, loose=loose, auths=auths).sum()))
         if self._resolve_loose(loose):
             lb = self._loose_bounds(f)
             if lb is not None:
@@ -1153,19 +1229,29 @@ class DeviceIndex:
                     # the bandwidth-champion path: Pallas dim-plane count,
                     # one dispatch, 12B/row
                     count_fn, _, kargs = self._dim_args(lb)
-                    return _fetch(int, count_fn(*kargs))
+                    return partial(_fetch, int, count_fn(*kargs))
                 m = self._z_mask_dev(lb)
                 if dv is not None:
                     m = m & dv
-                return _fetch(int, m.sum())
-        compiled, count_fn, _ = self._compiled_for(f)
-        if not compiled.device_cols or count_fn is None:
+                return partial(_fetch, int, m.sum())
+        compiled, count_fn, _, engine = self._compiled_for(f, key)
+        on_device = bool(compiled.device_cols) and count_fn is not None
+        from geomesa_tpu.filter.compile import has_polygon
+
+        if has_polygon(compiled.filter):
+            # where the point-in-polygon test runs
+            from geomesa_tpu import metrics
+
+            metrics.pip_queries.inc(
+                engine=engine if on_device and has_polygon(
+                    compiled.device_part) else "host")
+        if not on_device:
             m = compiled.host_mask(self._host_rows())
             hv = self._host_valid()
-            return int((m & hv).sum() if hv is not None else m.sum())
+            return _ready(int((m & hv).sum() if hv is not None else m.sum()))
         if not compiled.fully_on_device:
-            return len(self.query(query))
-        return _fetch(int, count_fn(self._resident_subset(compiled)))
+            return _ready(len(self.query(query)))
+        return count_fn(self._resident_subset(compiled))
 
     def loose_scan_kernel(self, query):
         """(count_fn, args) — the EXACT kernel + resident operands that
@@ -1403,12 +1489,12 @@ class DeviceIndex:
         from geomesa_tpu.failpoints import fail_point
 
         fail_point("fail.device.launch")  # chaos: resident scan launch
-        f = self._parse(query)
+        f, key = self._keyed(query)
         if self._resolve_loose(loose):
             lm = self._loose_mask(f)
             if lm is not None:
                 return self._apply_auths_np(lm, auths)
-        compiled, _, mask_fn = self._compiled_for(f)
+        compiled, _, mask_fn, _ = self._compiled_for(f, key)
         if not compiled.device_cols or mask_fn is None:
             m = compiled.host_mask(self._host_rows())
             hv = self._host_valid()
@@ -1620,7 +1706,7 @@ class DeviceIndex:
         if base_f is ast.Include:
             base_f = None
         if base_f is not None:
-            compiled, cfn, _ = self._compiled_for(base_f)
+            compiled, cfn, _, _ = self._compiled_for(base_f)
             if (
                 not compiled.device_cols
                 or not compiled.fully_on_device
@@ -1754,7 +1840,7 @@ class DeviceIndex:
             f = None
         compiled = None
         if f is not None:
-            compiled, cfn, _ = self._compiled_for(f)
+            compiled, cfn, _, _ = self._compiled_for(f)
             if (
                 not compiled.device_cols
                 or not compiled.fully_on_device
@@ -1847,7 +1933,7 @@ class DeviceIndex:
         if base_f is ast.Include:
             base_f = None
         if base_f is not None:
-            compiled, cfn, _ = self._compiled_for(base_f)
+            compiled, cfn, _, _ = self._compiled_for(base_f)
             if (
                 not compiled.device_cols
                 or not compiled.fully_on_device
@@ -2198,7 +2284,7 @@ class DeviceIndex:
             # whole-type stats must not fall back to the store path)
             kind = "include"
         if kind is None:
-            compiled, cfn, _ = self._compiled_for(f)
+            compiled, cfn, _, _ = self._compiled_for(f)
             if compiled.device_cols and compiled.fully_on_device and cfn:
                 kind = "exact"
             else:
@@ -2523,7 +2609,7 @@ class DeviceIndex:
                 m = self._z_mask_dev(lb)
                 dv = self._device_valid()
                 return (m & dv) if dv is not None else m
-        compiled, _, mask_fn = self._compiled_for(f)
+        compiled, _, mask_fn, _ = self._compiled_for(f)
         if (
             not compiled.device_cols
             or mask_fn is None
@@ -2965,8 +3051,11 @@ class StreamingDeviceIndex(DeviceIndex):
     # -- query hooks (scan bodies live in DeviceIndex) ---------------------
 
     def count(self, query, loose: "bool | None" = None, auths=None) -> int:
+        # the launch reads the donated buffers; the fetch waits outside
+        # the lock, so one count's copy overlaps the next count's launch
         with self._lock:
-            return super().count(query, loose=loose, auths=auths)
+            fetch = self._launch_count(query, loose=loose, auths=auths)
+        return fetch()
 
     def mask(
         self, query, loose: "bool | None" = None, auths=None

@@ -281,6 +281,32 @@ def _device_supported(f: ast.Filter, sft: SimpleFeatureType) -> bool:
     return False
 
 
+def has_polygon(f: ast.Filter) -> bool:
+    """Does the filter relate the data to a (multi)polygon?"""
+    if isinstance(f, (ast.And, ast.Or)):
+        return any(has_polygon(c) for c in f.children)
+    if isinstance(f, ast.Not):
+        return has_polygon(f.child)
+    return isinstance(f, ast.Intersects) and hasattr(f.geometry, "rings")
+
+
+def _on_tpu() -> bool:
+    import jax
+
+    return jax.devices()[0].platform == "tpu"
+
+
+def xla_counts(mask):
+    """A count fn's result from an XLA-fused mask: int32 [hits, 0, 0],
+    the shape of the Pallas kernels' [hits, row blocks tested, row
+    blocks skipped] (ops/pallas_scan.build_pallas_scan); the XLA scan
+    has no blocks."""
+    import jax.numpy as jnp
+
+    zero = jnp.int32(0)
+    return jnp.stack([jnp.sum(mask, dtype=jnp.int32), zero, zero])
+
+
 def _is_i64(sft: SimpleFeatureType, attr: str) -> bool:
     return sft.descriptor(attr).column_dtype == np.int64
 
@@ -501,9 +527,10 @@ class CompiledFilter:
         return self.residual_part is ast.Include
 
     def pallas_scan(self, **kw):
-        """(count_fn, mask_fn) Pallas TPU kernels for the device part, or
-        None when the filter can't be tiled (callers use device_fn). Cached
-        per CompiledFilter and option set."""
+        """(count_fn, mask_fn) jitted Pallas TPU kernels for the device
+        part (count_fn gives int32 [hits, blocks tested, blocks
+        skipped]), or None when the filter can't be tiled (callers use
+        device_fn). Cached per CompiledFilter and option set."""
         if not hasattr(self, "_pallas"):
             self._pallas = {}
         key = tuple(sorted(kw.items()))
@@ -527,22 +554,20 @@ class CompiledFilter:
         real TPUs and XLA-fused jnp elsewhere (interpret-mode pallas would
         crawl) or when the filter isn't tileable. The single source of the
         kernel-selection rule (used by the query runner and DeviceIndex);
-        cached per CompiledFilter."""
+        cached per CompiledFilter. count_fn gives int32 [hits, blocks
+        tested, blocks skipped] on either engine (:func:`xla_counts`)."""
         if not hasattr(self, "_jitted_scan"):
             import jax
 
-            scan = (
-                self.pallas_scan()
-                if jax.devices()[0].platform == "tpu"
-                else None
-            )
+            scan = self.pallas_scan() if _on_tpu() else None
             if scan is not None:
-                count_fn, mask_fn = jax.jit(scan[0]), jax.jit(scan[1])
+                count_fn, mask_fn = scan
             else:
                 mask_fn = jax.jit(self.device_fn)
-                count_fn = jax.jit(lambda c: self.device_fn(c).sum())
+                count_fn = jax.jit(lambda c: xla_counts(self.device_fn(c)))
             self._jitted_scan = (count_fn, mask_fn)
-            # which engine serves this filter (read by chip_smoke.py)
+            # the engine these fns run (read by chip_smoke.py); a resident
+            # index records the one it dispatches (DeviceIndex._compiled)
             self.scan_engine = "pallas" if scan is not None else "xla"
         return self._jitted_scan
 

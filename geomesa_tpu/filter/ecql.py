@@ -50,6 +50,40 @@ _TOKEN_RE = re.compile(
     re.VERBOSE,
 )
 
+#: geometry literals (geom/wkt.parse_wkt): the lexer hands a literal's
+#: whole text to the WKT reader as one ``wkt`` token, so a polygon of a
+#: thousand vertices is read once, not tokenized twice
+_WKT_WORDS = {
+    "POINT",
+    "LINESTRING",
+    "POLYGON",
+    "MULTIPOINT",
+    "MULTILINESTRING",
+    "MULTIPOLYGON",
+    "ENVELOPE",
+}
+_PAREN_RE = re.compile(r"[()]")
+_WKT_BODY_RE = re.compile(r"[\s\d.eE+\-(),]*")
+
+
+def _wkt_end(text: str, pos: int) -> "int | None":
+    """End of the balanced parenthesized WKT body at ``pos`` (after
+    optional blanks), or None when there is none, or it holds anything
+    but numbers, commas and parens (the token stream then says why)."""
+    j = pos
+    while j < len(text) and text[j].isspace():
+        j += 1
+    if not text.startswith("(", j):
+        return None
+    depth = 0
+    for m in _PAREN_RE.finditer(text, j):
+        depth += 1 if m.group() == "(" else -1
+        if depth == 0:
+            end = m.end()
+            return end if _WKT_BODY_RE.fullmatch(text, j, end) else None
+    return None
+
+
 _SPATIAL = {
     "BBOX",
     "INTERSECTS",
@@ -79,7 +113,14 @@ class _P:
             pos = m.end()
             for name, val in m.groupdict().items():
                 if val is not None:
-                    self.toks.append((name, val))
+                    end = (_wkt_end(text, pos)
+                           if name == "word" and val.upper() in _WKT_WORDS
+                           else None)
+                    if end is not None:
+                        self.toks.append(("wkt", val + text[pos:end]))
+                        pos = end
+                    else:
+                        self.toks.append((name, val))
                     break
         self.i = 0
 
@@ -182,27 +223,11 @@ def _not(p: _P) -> ast.Filter:
 
 
 def _wkt_geom(p: _P):
-    """Consume a WKT geometry (word + balanced parens) from the stream."""
-    kind, word = p.next()
-    if kind != "word":
-        raise ValueError(f"expected geometry, got {word!r}")
-    start = p.i
-    p.expect("lparen")
-    depth = 1
-    while depth:
-        k, v = p.next()
-        if k == "lparen":
-            depth += 1
-        elif k == "rparen":
-            depth -= 1
-    # reconstruct the wkt text span
-    toks = p.toks[start : p.i]
-    body = ""
-    for k, v in toks:
-        body += v if k != "comma" else ", "
-        if k in ("number",):
-            body += " "
-    return parse_wkt(word + " " + body)
+    """Consume a WKT geometry literal (one ``wkt`` token)."""
+    kind, text = p.next()
+    if kind != "wkt":
+        raise ValueError(f"expected geometry, got {text!r}")
+    return parse_wkt(text)
 
 
 def _primary(p: _P) -> ast.Filter:

@@ -78,12 +78,21 @@ def _coords_array(coords) -> np.ndarray:
     return a
 
 
+def _coords_repr(a: np.ndarray) -> str:
+    """Every coordinate, exactly: the resident caches key compiled
+    filters on ``repr``, and numpy's repr rounds and elides."""
+    return repr(a.tolist())
+
+
 @dataclass(frozen=True)
 class LineString(Geometry):
     coords: np.ndarray  # (n, 2)
 
     def __post_init__(self):
         object.__setattr__(self, "coords", _coords_array(self.coords))
+
+    def __repr__(self):
+        return f"LineString({_coords_repr(self.coords)})"
 
     @property
     def envelope(self) -> Envelope:
@@ -104,6 +113,10 @@ class Polygon(Geometry):
         object.__setattr__(
             self, "holes", tuple(_coords_array(h) for h in self.holes)
         )
+
+    def __repr__(self):
+        holes = ", ".join(_coords_repr(h) for h in self.holes)
+        return f"Polygon({_coords_repr(self.shell)}, holes=({holes}))"
 
     @property
     def envelope(self) -> Envelope:

@@ -52,18 +52,20 @@ def _number(tk: _Tokens) -> float:
 
 
 def _coord_seq(tk: _Tokens) -> np.ndarray:
+    """``(x y, x y, ...)`` as an (n, 2) array, read a sequence at a time:
+    the tokens up to the ``)`` must be numbers in pairs between commas."""
     tk.expect("(")
-    coords = []
-    while True:
-        x = _number(tk)
-        y = _number(tk)
-        coords.append((x, y))
-        t = tk.next()
-        if t == ")":
-            break
-        if t != ",":
-            raise ValueError(f"bad coordinate separator {t!r}")
-    return np.array(coords, dtype=np.float64)
+    toks, i = tk.toks, tk.i
+    try:
+        j = toks.index(")", i)
+    except ValueError:
+        raise ValueError("unexpected end of WKT") from None
+    seq = toks[i:j]
+    if len(seq) % 3 != 2 or seq[2::3].count(",") != len(seq) // 3:
+        raise ValueError(f"bad coordinate sequence {' '.join(seq[:9])!r}")
+    tk.i = j + 1
+    return np.stack([np.array(seq[0::3], np.float64),
+                     np.array(seq[1::3], np.float64)], axis=1)
 
 
 def _rings(tk: _Tokens) -> list[np.ndarray]:

@@ -506,6 +506,25 @@ slow_queries = REGISTRY.counter(
     "requests slower than trace.slow_ms (always-captured + slow-logged)",
 )
 
+# point-in-polygon filters on the resident count path: which engine
+# answered (pallas kernel, XLA broadcast, host), and the polygon kernel's
+# work: rows of the blocks it tested times their padded edges, and its
+# blocks by whether the pretests (envelope, window) let it skip the edges
+pip_queries = REGISTRY.counter(
+    "geomesa_pip_queries_total",
+    "resident counts of a point-in-polygon filter, by engine",
+)
+pip_edge_tests = REGISTRY.counter(
+    "geomesa_pip_edge_tests_total",
+    "polygon kernel: rows of tested blocks x padded polygon edges",
+)
+pip_blocks = REGISTRY.counter(
+    "geomesa_pip_blocks_total",
+    "polygon kernel row blocks, by outcome (tested|skipped)",
+)
+PIP_TESTED = pip_blocks.labels(outcome="tested")
+PIP_SKIPPED = pip_blocks.labels(outcome="skipped")
+
 # served-path stages (tracing.STAGES): every span of a declared stage
 # observes its wall time and adds its thread CPU time here, whether or
 # not the request's trace is recorded. The stage label takes only the

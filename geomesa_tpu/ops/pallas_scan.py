@@ -12,9 +12,16 @@ One HBM read per byte of scanned data; no intermediate materialization.
 Columns reaching the kernel are 32-bit lanes only: float32/int32/uint32
 scalars, point coords as ``__x``/``__y`` float32, and int64 (Date/Long)
 columns pre-split into ``__hi``/``__lo`` word planes (ops/int64lanes.py).
-Filters whose device part needs anything else (float64 columns, huge
-polygon edge lists) fall back to the XLA-fused jnp path in
-filter/compile.py -- same semantics, same staged columns.
+Filters whose device part needs anything else (float64 columns, polygons
+over :data:`MAX_KERNEL_EDGES` edges) fall back to the XLA-fused jnp path
+in filter/compile.py -- same semantics, same staged columns.
+
+A filter with a point-in-polygon conjunct takes the polygon kernel
+(:func:`_build_pip_scan`): the polygon's edge table, its envelope and
+the DURING/BBOX bounds ride in SMEM as runtime operands, so one compiled
+program serves every filter of the same shape and edge bucket, and the
+edge loop runs only on the row blocks where some row passes the cheap
+exact pretests the predicate implies (the envelope, the time window).
 
 On CPU jax (tests / CI) the kernel runs in interpret mode, so the whole
 suite exercises the identical kernel code without a TPU.
@@ -29,9 +36,19 @@ from geomesa_tpu.filter import ast
 from geomesa_tpu.ops.int64lanes import cmp_jax
 
 LANES = 128
-# Unrolled edge budget for in-kernel point-in-polygon; bigger rings fall
-# back to the jnp path (broadcasting (n, E) there is fine in HBM).
-MAX_KERNEL_EDGES = 64
+#: a polygon's edge table is padded to a bucket of the shape ladder (a
+#: power of two by default) between these, with degenerate y_lo == y_hi
+#: edges, which never straddle: one compiled kernel serves every polygon
+#: of a bucket. The table rides in SMEM; polygons over the largest
+#: bucket take the XLA path
+MIN_EDGE_BUCKET = 16
+MAX_KERNEL_EDGES = 4096
+#: sublane rows of a skip block of the polygon kernel (x 128 lanes): the
+#: pretest and the edge loop run per block. 32 is the int8 tile of the
+#: mask and of the validity plane; on a TPU v5e blocks of 8 rows spent
+#: ~8.5 ms a call over 2^25 rows in their pretests alone (PERF.md)
+SKIP_ROWS = 32
+_EDGE_UNROLL = 8
 _VMEM_BUDGET = 6 * 1024 * 1024
 
 
@@ -64,44 +81,165 @@ def supported_columns(f: ast.Filter, sft: SimpleFeatureType) -> list[str]:
     return cols
 
 
-def _build_tile_fn(f: ast.Filter, sft: SimpleFeatureType):
-    """AST -> fn(cols: dict[str, 2-D tile]) -> bool tile. Mirrors
-    filter/compile.build_device_fn but restricted to ops that lower to
-    Pallas TPU (elementwise VPU work on 32-bit lanes, static unrolls)."""
+def _tile_shape(cols: dict):
+    return next(iter(cols.values())).shape
 
-    def rec(node):
+
+def _tile_mats(cols: list, br: int, coldict: dict, valid):
+    """(rows, grid steps, the columns as zero-padded (steps * br, 128)
+    tiles, then the validity plane as int8, if any)."""
+    import jax.numpy as jnp
+
+    n = int(_tile_shape(coldict)[0])
+    if n > 2**31 - 1 - br * LANES:
+        raise PallasUnsupported("partition too large for int32 indexing")
+    grid = max(1, -(-n // (br * LANES)))
+    pad = grid * br * LANES - n
+    mats = [jnp.pad(coldict[c], (0, pad)).reshape(grid * br, LANES)
+            for c in cols]
+    if valid is not None:
+        # int8 tile (Mosaic has no bool memref); sublane rows are a
+        # multiple of 32, the int8 tiling
+        mats.append(jnp.pad(valid.astype(jnp.int8), (0, pad)).reshape(
+            grid * br, LANES))
+    return n, grid, mats
+
+
+def _pick_block_rows(n_cols: int) -> int:
+    rows = _VMEM_BUDGET // max(1, n_cols * LANES * 4)
+    rows = max(64, min(1024, rows))
+    return (rows // 32) * 32  # int8/int32 sublane multiple
+
+
+# -- point in polygon ------------------------------------------------------
+
+
+def edge_bucket(n_edges: int) -> int:
+    """The bucket a polygon's edge table is padded to: the shape ladder's
+    rung (a power of two by default), a multiple of the loop's unroll."""
+    from geomesa_tpu.bucketing import bucket_cap
+
+    b = bucket_cap(n_edges, MIN_EDGE_BUCKET)
+    return -(-b // _EDGE_UNROLL) * _EDGE_UNROLL
+
+
+def edge_table(rings) -> np.ndarray:
+    """(4, bucket) float32 table of closed rings' edges: per edge its
+    lower y, upper y, the x at the lower y, and dx/dy. Endpoints are
+    ordered by y, so an edge two rings share tests alike in both. The
+    padding edges have y_lo == y_hi == 0 and never straddle."""
+    from geomesa_tpu.geom.predicates import polygon_edges
+
+    x1, y1, x2, y2 = polygon_edges(rings)
+    up = y1 <= y2
+    xa = np.where(up, x1, x2)
+    ya, yb = np.where(up, y1, y2), np.where(up, y2, y1)
+    dy = yb - ya
+    slope = np.divide(np.where(up, x2, x1) - xa, dy, out=np.zeros_like(dy),
+                      where=dy != 0)
+    t = np.zeros((4, edge_bucket(len(xa))), np.float32)
+    t[:, : len(xa)] = (ya, yb, xa, slope)
+    return t
+
+
+def _f32_bounds(lo: float, hi: float, outward: bool):
+    """float32 bounds for ``lo <= v <= hi`` over float32 ``v``: exact
+    (the nearest float32 inside), or ``outward`` (the nearest outside,
+    for an envelope that must hold every vertex)."""
+    a, b = np.float32(lo), np.float32(hi)
+    down, up = np.float32(-np.inf), np.float32(np.inf)
+    if outward:
+        a = np.nextafter(a, down) if a > lo else a
+        b = np.nextafter(b, up) if b < hi else b
+    else:
+        a = np.nextafter(a, up) if a < lo else a
+        b = np.nextafter(b, down) if b > hi else b
+    return a, b
+
+
+class _TilePlan:
+    """A filter's device part as a predicate over a tile of columns, for
+    both kernels: ``full(cols, q)`` is the predicate; ``cheap(cols, q)``
+    is implied by it and runs no edge loop (None: no pretest, every
+    block is tested).
+
+    With ``lift`` (the polygon kernel) BBOX bounds, polygon envelopes and
+    edge tables go to ``floats``, the int64 bounds of DURING/BETWEEN to
+    ``hi``/``lo`` as int32/uint32 words; ``q`` is (floats, hi, lo), read
+    from SMEM at run time, so the filters of one ``key`` (attributes,
+    ops, edge buckets, and the literals that stay in the program) share
+    one program. Without it (the bbox kernel) every literal is baked in
+    and ``q`` is unused: one program per filter text."""
+
+    def __init__(self, f: ast.Filter, sft: SimpleFeatureType, lift: bool):
+        self.sft, self.lift = sft, lift
+        self._floats: list = []
+        self._n_floats = 0
+        self.hi: list = []
+        self.lo: list = []
+        self._key: list = []
+        self.cheap, self.full = self._rec(f)
+        self.key = " ".join(self._key)
+        self.floats = np.concatenate(self._floats + [np.zeros(1, np.float32)])
+
+    def _add_floats(self, vals) -> int:
+        a = np.asarray(vals, np.float32).ravel()
+        off = self._n_floats
+        self._floats.append(a)
+        self._n_floats += len(a)
+        return off
+
+    def _rec(self, node):
+        """(cheap, full) of a node; ``cheap is full`` where the node
+        holds no polygon."""
         import jax.numpy as jnp
 
-        if node is ast.Include:
-            return lambda cols: jnp.full(_tile_shape(cols), True, dtype=bool)
-        if node is ast.Exclude:
-            return lambda cols: jnp.full(_tile_shape(cols), False, dtype=bool)
+        from geomesa_tpu.filter.compile import _device_supported, \
+            build_device_fn
+        from geomesa_tpu.geom import Point
+        from geomesa_tpu.ops.int64lanes import cmp_lanes_jax, split_value
+
+        sft, lift = self.sft, self.lift
         if isinstance(node, (ast.And, ast.Or)):
-            fns = [rec(c) for c in node.children]
             is_and = isinstance(node, ast.And)
-
-            def f_bool(cols, fns=fns, is_and=is_and):
-                m = fns[0](cols)
-                for fn in fns[1:]:
-                    m = (m & fn(cols)) if is_and else (m | fn(cols))
-                return m
-
-            return f_bool
+            self._key.append("And(" if is_and else "Or(")
+            parts = [self._rec(c) for c in node.children]
+            self._key.append(")")
+            full = _fold([p[1] for p in parts], is_and)
+            if all(c is fl for c, fl in parts):
+                return full, full
+            cheaps = [c for c, _ in parts if c is not None]
+            if not is_and and len(cheaps) < len(parts):
+                return None, full
+            return (_fold(cheaps, is_and) if cheaps else None), full
         if isinstance(node, ast.Not):
-            fn = rec(node.child)
-            return lambda cols, fn=fn: ~fn(cols)
-        if isinstance(node, ast.BBox):
-            if not sft.descriptor(node.attr).is_point:
-                # envelope-overlap tile: delegate so the compare stays
-                # bit-identical to the XLA path (single source, same as
-                # the During/Compare delegation below)
-                from geomesa_tpu.filter.compile import build_device_fn
+            self._key.append("Not(")
+            cheap, inner = self._rec(node.child)
+            self._key.append(")")
 
-                inner = build_device_fn(node, sft)
-                return lambda cols, inner=inner: inner(cols)
+            def neg(cols, q, inner=inner):
+                return ~inner(cols, q)
+
+            # the complement of a pretest is not implied by the complement
+            return (neg if cheap is inner else None), neg
+        if node is ast.Include or node is ast.Exclude:
+            val = node is ast.Include
+            self._key.append(repr(node))
+
+            def const(cols, q, val=val):
+                return jnp.full(_tile_shape(cols), val, dtype=bool)
+
+            return const, const
+        if isinstance(node, ast.BBox) and sft.descriptor(node.attr).is_point:
+            if lift:
+                x0, x1 = _f32_bounds(node.xmin, node.xmax, outward=False)
+                y0, y1 = _f32_bounds(node.ymin, node.ymax, outward=False)
+                fn = _box_fn(node.attr, self._add_floats([x0, y0, x1, y1]))
+                self._key.append(f"BBox({node.attr})")
+                return fn, fn
             ax, ay = f"{node.attr}__x", f"{node.attr}__y"
 
-            def f_bbox(cols, node=node, ax=ax, ay=ay):
+            def f_bbox(cols, q, node=node, ax=ax, ay=ay):
                 x, y = cols[ax], cols[ay]
                 return (
                     (x >= node.xmin)
@@ -110,94 +248,314 @@ def _build_tile_fn(f: ast.Filter, sft: SimpleFeatureType):
                     & (y <= node.ymax)
                 )
 
-            return f_bbox
-        if isinstance(node, ast.DWithin):
-            from geomesa_tpu.geom import Point
-
-            if not (
-                sft.descriptor(node.attr).is_point
-                and isinstance(node.geometry, Point)
-            ):
-                # padded-envelope bbox: delegate to the single XLA-path
-                # implementation (build_device_fn rewrites to BBox)
-                from geomesa_tpu.filter.compile import build_device_fn
-
-                inner = build_device_fn(node, sft)
-                return lambda cols, inner=inner: inner(cols)
+            self._key.append(repr(node))
+            return f_bbox, f_bbox
+        if isinstance(node, ast.DWithin) and sft.descriptor(
+                node.attr).is_point and isinstance(node.geometry, Point):
             ax, ay = f"{node.attr}__x", f"{node.attr}__y"
 
-            def f_dw(cols, node=node, ax=ax, ay=ay):
+            def f_dw(cols, q, node=node, ax=ax, ay=ay):
                 dx = cols[ax] - node.geometry.x
                 dy = cols[ay] - node.geometry.y
                 return dx * dx + dy * dy <= node.distance**2
 
-            return f_dw
-        if isinstance(node, ast.Intersects):
+            self._key.append(repr(node))
+            return f_dw, f_dw
+        if lift and isinstance(node, ast.Intersects):
+            _check(_device_supported(node, sft),
+                   "intersects shape not kernelizable")
+            table = edge_table(node.geometry.rings())
+            n_edges = table.shape[1]
             _check(
-                sft.descriptor(node.attr).is_point
-                and hasattr(node.geometry, "rings")
-                and node.op in ("intersects", "within", "disjoint"),
-                "intersects shape not kernelizable",
+                n_edges <= MAX_KERNEL_EDGES,
+                f"{n_edges} polygon edges > kernel edge bucket",
             )
-            from geomesa_tpu.geom.predicates import polygon_edges
-
-            x1, y1, x2, y2 = polygon_edges(node.geometry.rings())
-            _check(
-                len(x1) <= MAX_KERNEL_EDGES,
-                f"{len(x1)} polygon edges > kernel unroll budget",
-            )
-            edges = [
-                (float(a), float(b), float(c), float(d))
-                for a, b, c, d in zip(x1, y1, x2, y2)
-            ]
+            e = node.geometry.envelope
+            x0, x1 = _f32_bounds(e.xmin, e.xmax, outward=True)
+            y0, y1 = _f32_bounds(e.ymin, e.ymax, outward=True)
+            env = _box_fn(node.attr, self._add_floats([x0, y0, x1, y1]))
+            off = self._add_floats(table)
+            self._key.append(f"Intersects({node.attr},{node.op},{n_edges})")
             ax, ay = f"{node.attr}__x", f"{node.attr}__y"
-            neg = node.op == "disjoint"
 
-            def f_pip(cols, edges=edges, ax=ax, ay=ay, neg=neg):
-                # crossing-number test, edges unrolled as scalar constants
-                px, py = cols[ax], cols[ay]
-                crossings = jnp.zeros(px.shape, dtype=jnp.int32)
-                for ex1, ey1, ex2, ey2 in edges:
-                    straddle = (ey1 > py) != (ey2 > py)
-                    denom = (ey2 - ey1) if ey2 != ey1 else 1.0
-                    xint = ex1 + (py - ey1) * (ex2 - ex1) / denom
-                    crossings = crossings + (straddle & (px < xint))
-                # parity via bitwise AND: `crossings % 2` trips an
-                # infinite _convert_element_type recursion in the Mosaic
-                # lowering when x64 is enabled (the weak int literal
-                # round-trips through i64) — pinned by
-                # tests/test_pallas_scan.py::test_mosaic_mod_recursion_repro
-                m = (crossings & 1) == 1
-                return ~m if neg else m
+            def inside(cols, q, env=env, off=off, n=n_edges, ax=ax, ay=ay):
+                # every hit lies in the envelope: the crossing parity can
+                # flip a point an ulp outside it, the envelope cannot
+                return env(cols, q) & _crossing_parity(
+                    cols[ax], cols[ay], q[0], off, n
+                )
 
-            return f_pip
-        if isinstance(node, (ast.During, ast.Between, ast.Compare, ast.In)):
-            # identical numeric semantics to build_device_fn -- delegate so
-            # the i64 hi/lo rewrite and float-bound rounding stay in one
-            # place (the inner closures are pure elementwise jnp).
-            from geomesa_tpu.filter.compile import (
-                _device_supported,
-                build_device_fn,
-            )
+            if node.op == "disjoint":
+                return None, lambda cols, q, inside=inside: ~inside(cols, q)
+            return env, inside
+        if lift and isinstance(node, (ast.During, ast.Between)) and \
+                sft.descriptor(node.attr).column_dtype == np.int64:
+            import math
 
-            _check(_device_supported(node, sft), f"{type(node).__name__}")
-            inner = build_device_fn(node, sft)
-            return lambda cols, inner=inner: inner(cols)
-        raise PallasUnsupported(f"node {type(node).__name__}")
+            lo = node.t0 if isinstance(node, ast.During) else node.lo
+            hi = node.t1 if isinstance(node, ast.During) else node.hi
+            k = len(self.hi)
+            for v in (math.ceil(lo), math.floor(hi)):
+                vhi, vlo = split_value(v)
+                self.hi.append(vhi)
+                self.lo.append(vlo)
+            chi, clo = f"{node.attr}__hi", f"{node.attr}__lo"
 
-    import jax.numpy as jnp  # noqa: F401 (closures above)
+            def window(cols, q, k=k, chi=chi, clo=clo):
+                h, lw = cols[chi], cols[clo]
+                return cmp_lanes_jax(">=", h, lw, q[1][k], q[2][k]) & \
+                    cmp_lanes_jax("<=", h, lw, q[1][k + 1], q[2][k + 1])
 
-    return rec(f)
+            self._key.append(f"{type(node).__name__}({node.attr})")
+            return window, window
+        # any other leaf keeps its literals in the program (and in the
+        # key): the XLA path's own closure over the tile, so the i64
+        # hi/lo rewrite, float-bound rounding and the envelope compares
+        # of non-point geometries stay in one place
+        _check(_device_supported(node, sft), f"node {type(node).__name__}")
+        inner = build_device_fn(node, sft)
+        self._key.append(repr(node))
+
+        def baked(cols, q, inner=inner):
+            return inner(cols)
+
+        return baked, baked
 
 
-def _tile_shape(cols: dict):
-    return next(iter(cols.values())).shape
+def _fold(fns: list, is_and: bool):
+    def fold(cols, q, fns=fns, is_and=is_and):
+        m = fns[0](cols, q)
+        for fn in fns[1:]:
+            m = (m & fn(cols, q)) if is_and else (m | fn(cols, q))
+        return m
+
+    return fold
 
 
-def _pick_block_rows(n_cols: int) -> int:
-    rows = _VMEM_BUDGET // max(1, n_cols * LANES * 4)
-    rows = max(64, min(1024, rows))
-    return (rows // 32) * 32  # int8/int32 sublane multiple
+def _box_fn(attr: str, off: int):
+    """``x0 <= x <= x1 and y0 <= y <= y1`` with the bounds at ``off`` of
+    the float table."""
+    ax, ay = f"{attr}__x", f"{attr}__y"
+
+    def box(cols, q):
+        fq, x, y = q[0], cols[ax], cols[ay]
+        return (x >= fq[off]) & (y >= fq[off + 1]) & (x <= fq[off + 2]) & (
+            y <= fq[off + 3])
+
+    return box
+
+
+def _crossing_parity(px, py, fq, off: int, n_edges: int):
+    """Crossing-number parity of points against the edge table at
+    ``off`` of the float table, the edges read from SMEM in a loop: an
+    edge crosses the ray east of a point when y_lo <= py < y_hi and px
+    lies west of the edge at py."""
+    import jax
+    import jax.numpy as jnp
+
+    # int32 offsets and bounds: under x64 a Python int (and a static
+    # fori_loop's counter) traces as i64, whose convert Mosaic's lowering
+    # recurses on. So the loop runs over traced int32 bounds, and unrolls
+    # by hand: _EDGE_UNROLL edges a step (buckets are multiples of it)
+    o_lo, o_hi, o_x, o_dx = (jnp.int32(off + j * n_edges) for j in range(4))
+
+    def edges(step, odd):
+        k0 = step * jnp.int32(_EDGE_UNROLL)
+        for u in range(_EDGE_UNROLL):
+            k = k0 + jnp.int32(u)
+            y_lo = fq[o_lo + k]
+            x = fq[o_x + k] + (py - y_lo) * fq[o_dx + k]
+            hit = (py >= y_lo) & (py < fq[o_hi + k]) & (px < x)
+            odd = odd ^ hit.astype(jnp.int32)
+        return odd
+
+    odd = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(n_edges // _EDGE_UNROLL), edges,
+        jnp.zeros(px.shape, jnp.int32),
+    )
+    return odd != jnp.int32(0)
+
+
+#: (filter shape, columns, block rows, interpret) -> jitted (count, mask)
+_PIP_PROGRAMS: dict = {}
+
+
+def _pip_programs(plan: _TilePlan, cols: list, br: int, interpret: bool):
+    """The jitted polygon programs for a plan's shape, built once per
+    process: filters that differ only in lifted literals share them."""
+    key = (plan.key, tuple(cols), br, interpret)
+    progs = _PIP_PROGRAMS.get(key)
+    if progs is None:
+        progs = _PIP_PROGRAMS.setdefault(
+            key, _pip_kernels(plan.cheap, plan.full, cols, br, interpret)
+        )
+    return progs
+
+
+def _pip_kernels(cheap, full, cols: list, br: int, interpret: bool):
+    """Jitted ``pip_count(coldict, fq, iq, uq, valid)`` -> int32
+    [hits, blocks tested, blocks skipped] and ``pip_mask(...)`` -> bool
+    mask. Each grid
+    step walks its tile in blocks of ``SKIP_ROWS`` x 128; a block whose
+    live rows all fail ``cheap`` skips the predicate (its edge loop)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def _zero():
+        return jnp.int32(0)
+
+    def _any(m):
+        return jnp.max(m.astype(jnp.int32)) > jnp.int32(0)
+
+    def _walk(n: int, has_valid: bool, on_block):
+        """The kernel body over one tile: ``on_block(r0, sub, pre, q)``
+        for each block of ``SKIP_ROWS`` sublanes, ``pre`` the live rows
+        that pass the pretest."""
+
+        def body(fq, iq, uq, in_refs):
+            q = (fq, iq, uq)
+            i = pl.program_id(0)
+
+            def block(b, carry):
+                # int32 literals throughout: see _crossing_parity
+                r0 = pl.multiple_of(b * jnp.int32(SKIP_ROWS), SKIP_ROWS)
+                sub = {c: r[pl.ds(r0, SKIP_ROWS), :] for c, r in zip(cols, in_refs)}
+                idx = (
+                    i * jnp.int32(br * LANES)
+                    + (r0 + jax.lax.broadcasted_iota(
+                        jnp.int32, (SKIP_ROWS, LANES), 0)) * jnp.int32(LANES)
+                    + jax.lax.broadcasted_iota(jnp.int32, (SKIP_ROWS, LANES), 1)
+                )
+                pre = idx < jnp.int32(n)
+                if has_valid:
+                    pre = pre & (in_refs[-1][pl.ds(r0, SKIP_ROWS), :]
+                                 != jnp.int8(0))
+                if cheap is not None:
+                    pre = pre & cheap(sub, q)
+                on_block(r0, sub, pre, q)
+                return carry
+
+            jax.lax.fori_loop(_zero(), jnp.int32(br // SKIP_ROWS), block, _zero())
+
+        return body
+
+    def _specs(n_in: int, out_specs, grid: int):
+        return pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(grid,),
+            in_specs=[pl.BlockSpec((br, LANES), lambda i, *_: (i, _zero()))
+                      for _ in range(n_in)],
+            out_specs=out_specs,
+        )
+
+    def pip_count(coldict, fq, iq, uq, valid=None):
+        n, grid, mats = _tile_mats(cols, br, coldict, valid)
+
+        def kernel(fq_ref, iq_ref, uq_ref, *refs):
+            in_refs, (hits_ref, tested_ref) = refs[:-2], refs[-2:]
+
+            @pl.when(pl.program_id(0) == 0)
+            def _():
+                hits_ref[...] = jnp.zeros((1, LANES), jnp.int32)
+                tested_ref[...] = jnp.zeros((1, LANES), jnp.int32)
+
+            def on_block(r0, sub, pre, q):
+                @pl.when(_any(pre))
+                def _():
+                    m = pre & full(sub, q)
+                    hits_ref[...] += jnp.sum(
+                        m.astype(jnp.int32), axis=0, dtype=jnp.int32,
+                        keepdims=True)
+                    tested_ref[...] += jnp.int32(1)
+
+            _walk(n, valid is not None, on_block)(
+                fq_ref, iq_ref, uq_ref, in_refs)
+
+        acc = pl.BlockSpec((1, LANES), lambda i, *_: (_zero(), _zero()))
+        hits, tested = pl.pallas_call(
+            kernel,
+            grid_spec=_specs(len(mats), [acc, acc], grid),
+            out_shape=[jax.ShapeDtypeStruct((1, LANES), jnp.int32)] * 2,
+            interpret=interpret,
+            name="pip_count",
+        )(fq, iq, uq, *mats)
+        blocks = jnp.int32(grid * (br // SKIP_ROWS))
+        return jnp.stack([jnp.sum(hits, dtype=jnp.int32), tested[0, 0],
+                          blocks - tested[0, 0]])
+
+    def pip_mask(coldict, fq, iq, uq, valid=None):
+        n, grid, mats = _tile_mats(cols, br, coldict, valid)
+
+        def kernel(fq_ref, iq_ref, uq_ref, *refs):
+            in_refs, out_ref = refs[:-1], refs[-1]
+
+            def on_block(r0, sub, pre, q):
+                out_ref[pl.ds(r0, SKIP_ROWS), :] = jnp.zeros((SKIP_ROWS, LANES),
+                                                        jnp.int8)
+
+                @pl.when(_any(pre))
+                def _():
+                    out_ref[pl.ds(r0, SKIP_ROWS), :] = (
+                        pre & full(sub, q)).astype(jnp.int8)
+
+            _walk(n, valid is not None, on_block)(
+                fq_ref, iq_ref, uq_ref, in_refs)
+
+        m = pl.pallas_call(
+            kernel,
+            grid_spec=_specs(len(mats), pl.BlockSpec(
+                (br, LANES), lambda i, *_: (i, _zero())), grid),
+            out_shape=jax.ShapeDtypeStruct((grid * br, LANES), jnp.int8),
+            interpret=interpret,
+            name="pip_mask",
+        )(fq, iq, uq, *mats)
+        return m.reshape(-1)[:n].astype(bool)
+
+    return jax.jit(pip_count), jax.jit(pip_mask)
+
+
+def padded_edges(f: ast.Filter) -> int:
+    """The polygon kernel's edges for a filter: each polygon's edge
+    count padded to its bucket, summed. A row of a tested block is
+    tested against each."""
+    if isinstance(f, (ast.And, ast.Or)):
+        return sum(padded_edges(c) for c in f.children)
+    if isinstance(f, ast.Not):
+        return padded_edges(f.child)
+    if isinstance(f, ast.Intersects) and hasattr(f.geometry, "rings"):
+        return edge_bucket(sum(len(r) - 1 for r in f.geometry.rings()))
+    return 0
+
+
+def _build_pip_scan(f, sft, cols, br, interpret):
+    """(count_fn, mask_fn) of the polygon kernel, bound to this filter's
+    runtime operands (see :func:`build_pallas_scan`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomesa_tpu import ledger
+
+    plan = _TilePlan(f, sft, lift=True)
+    count_prog, mask_prog = _pip_programs(plan, cols, br, interpret)
+    with jax.ensure_compile_time_eval():  # resident, even if built in a trace
+        ops = (jnp.asarray(plan.floats),
+               jnp.asarray(np.asarray(plan.hi + [0], np.int32)),
+               jnp.asarray(np.asarray(plan.lo + [0], np.uint32)))
+
+    # one compiled program per filter shape and edge bucket
+    scope = f"cache.scan:pip:e={padded_edges(f)}"
+
+    def count_fn(coldict, valid=None):
+        with ledger.compile_scope(scope):
+            return count_prog(coldict, *ops, valid)
+
+    def mask_fn(coldict, valid=None):
+        with ledger.compile_scope(scope):
+            return mask_prog(coldict, *ops, valid)
+
+    return count_fn, mask_fn
 
 
 def build_pallas_scan(
@@ -207,13 +565,16 @@ def build_pallas_scan(
     block_rows: int | None = None,
     interpret: bool | None = None,
 ):
-    """Compile the filter's device part to Pallas count/mask callables.
+    """Compile the filter's device part to jitted Pallas count/mask
+    callables.
 
     Returns ``(count_fn, mask_fn, cols)`` where each fn takes a dict of
-    staged 1-D device columns (see ops/scan.stage_columns) and returns the
-    int32 hit count / bool mask for the whole array. Either fn takes an
-    optional bool ``valid`` plane (the padded buffers of a streaming
-    resident index): rows where it is False never match. Raises
+    staged 1-D device columns (see ops/scan.stage_columns) and returns
+    int32 [hits, row blocks tested, row blocks skipped] / the bool mask
+    for the whole array: the polygon kernel skips blocks (``SKIP_ROWS``
+    x 128 rows), the bbox kernel tests every tile of its grid. Either fn
+    takes an optional bool ``valid`` plane (the padded buffers of a
+    streaming resident index): rows where it is False never match. Raises
     PallasUnsupported when the filter can't be tiled; callers fall back to
     CompiledFilter.device_fn.
     """
@@ -223,30 +584,17 @@ def build_pallas_scan(
 
     cols = supported_columns(f, sft)
     _check(bool(cols), "no device columns (constant filter)")
-    tile_fn = _build_tile_fn(f, sft)
     br = block_rows or _pick_block_rows(len(cols))
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
+    from geomesa_tpu.filter.compile import has_polygon
 
-    def _prep(coldict, valid):
-        n = int(_tile_shape(coldict)[0])
-        if n > 2**31 - 1 - br * LANES:
-            raise PallasUnsupported("partition too large for int32 indexing")
-        grid = max(1, -(-n // (br * LANES)))
-        pad = grid * br * LANES - n
-        mats = [
-            jnp.pad(coldict[c], (0, pad)).reshape(grid * br, LANES)
-            for c in cols
-        ]
-        if valid is not None:
-            # int8 tile (Mosaic has no bool memref); sublane rows are a
-            # multiple of 32, the int8 tiling
-            mats.append(
-                jnp.pad(valid.astype(jnp.int8), (0, pad)).reshape(
-                    grid * br, LANES
-                )
-            )
-        return n, grid, pad, mats
+    if has_polygon(f):
+        return (*_build_pip_scan(f, sft, cols, br, interpret), cols)
+    plan = _TilePlan(f, sft, lift=False)
+
+    def tile_fn(cols):
+        return plan.full(cols, None)
 
     def _valid_mask(n, has_valid):
         # rows past n (tile padding), and rows the validity plane marks
@@ -276,7 +624,7 @@ def build_pallas_scan(
         ]
 
     def count_fn(coldict, valid=None):
-        n, grid, pad, mats = _prep(coldict, valid)
+        n, grid, mats = _tile_mats(cols, br, coldict, valid)
         tail = _valid_mask(n, valid is not None)
 
         def kernel(*refs):
@@ -309,10 +657,11 @@ def build_pallas_scan(
             interpret=interpret,
         )(*mats)
         # final 128-way fold runs in XLA outside the kernel
-        return jnp.sum(partials, dtype=jnp.int32)
+        return jnp.stack([jnp.sum(partials, dtype=jnp.int32),
+                          jnp.int32(grid), jnp.int32(0)])
 
     def mask_fn(coldict, valid=None):
-        n, grid, pad, mats = _prep(coldict, valid)
+        n, grid, mats = _tile_mats(cols, br, coldict, valid)
         tail = _valid_mask(n, valid is not None)
 
         def kernel(*refs):
@@ -332,4 +681,4 @@ def build_pallas_scan(
         )(*mats)
         return m.reshape(-1)[:n].astype(bool)
 
-    return count_fn, mask_fn, cols
+    return jax.jit(count_fn), jax.jit(mask_fn), cols

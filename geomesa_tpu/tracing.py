@@ -89,13 +89,15 @@ _current: contextvars.ContextVar = contextvars.ContextVar(
 #: counted (the bounded ``stage`` label) and annotated on the profiler's
 #: clock. ``http.*`` run on the handler thread, ``sched.execute`` on a
 #: scheduler worker, ``device.fetch`` is the blocking host copy of a
-#: resident count/density result. Declare a name here only for a reader
+#: resident count/density result, ``cql.parse`` the resident index's
+#: parse of a request's CQL text. Declare a name here only for a reader
 #: that uses it: each pays its counters on every request
 STAGES = (
     "http.request",
     "http.encode",
     "sched.execute",
     "device.fetch",
+    "cql.parse",
 )
 #: each stage's label key, built once: spans of these names run on
 #: every request

@@ -50,6 +50,37 @@ def test_resident_count_and_query_match_oracle():
         )
 
 
+def test_repeated_cql_is_parsed_once(monkeypatch):
+    """A CQL string's filter and cache key are remembered: a repeated
+    count parses nothing and answers the same; the memo starts over when
+    it holds ``_TEXTS_MAX`` strings."""
+    from geomesa_tpu import device_cache
+    from geomesa_tpu.filter import ecql
+
+    ds = _store(n=3000)
+    di = DeviceIndex(ds, "t")
+    all_batch = ds.query("t").batch
+    texts = [f"val >= {v} AND BBOX(geom, -90, -45, 90, 45)"
+             for v in (10, 50, 90)]
+    calls = []
+    parse = ecql.parse_ecql
+
+    def counted(text):
+        calls.append(text)
+        return parse(text)
+
+    monkeypatch.setattr(ecql, "parse_ecql", counted)
+    want = [int(evaluate_host(parse(t), all_batch).sum()) for t in texts]
+    assert [di.count(texts[0]) for _ in range(3)] == [want[0]] * 3
+    assert calls == texts[:1]
+    assert di.count(parse(texts[0])) == want[0]  # an AST shares the entry
+    assert len(di._compiled) == 1
+    monkeypatch.setattr(device_cache, "_TEXTS_MAX", 2)
+    assert [di.count(t) for t in texts] == want
+    assert len(di._texts) <= 2
+    assert calls == texts
+
+
 def test_residual_filters_still_exact():
     ds = _store(n=2000)
     di = DeviceIndex(ds, "t")
@@ -266,6 +297,47 @@ class TestStreamingDeviceIndex:
             np.sort(di.query(self.ECQL).fids.astype(np.int64)),
             np.sort(all_batch.fids[expect & live].astype(np.int64)),
         )
+
+    def test_count_fetches_outside_the_lock(self, monkeypatch):
+        """The lock covers a count's launch alone: the fetch finds it
+        free, and an append that donates the launched planes before the
+        fetch leaves the launched count's answer as it was."""
+        import threading
+
+        from geomesa_tpu import device_cache
+        from geomesa_tpu.device_cache import StreamingDeviceIndex
+
+        ds = _store(n=5000)
+        di = StreamingDeviceIndex(ds, "t", capacity=1 << 15)
+        _, expect = _oracle(ds, self.ECQL)
+        free = []
+        fetch = device_cache._fetch
+
+        def probe(conv, x):
+            def other():
+                got = di._lock.acquire(blocking=False)
+                if got:
+                    di._lock.release()
+                free.append(got)
+
+            t = threading.Thread(target=other)
+            t.start()
+            t.join()
+            return fetch(conv, x)
+
+        monkeypatch.setattr(device_cache, "_fetch", probe)
+        assert di.count(self.ECQL) == int(expect.sum())
+        assert free == [True]
+        with di._lock:
+            pending = di._launch_count(self.ECQL)
+        sft = ds.get_schema("t")
+        b = self._batch(sft, 500, seed=41, fid0=100_000)
+        ds.write("t", dict(b.columns), fids=b.fids)
+        di.append(b)
+        assert di.delta_appends == 1
+        assert pending() == int(expect.sum())
+        _, after = _oracle(ds, self.ECQL)
+        assert di.count(self.ECQL) == int(after.sum())
 
     def test_residual_and_host_filters_respect_validity(self):
         from geomesa_tpu.device_cache import StreamingDeviceIndex

@@ -92,6 +92,51 @@ class TestParse:
             with pytest.raises(ValueError):
                 parse_ecql(bad)
 
+    def test_geometry_literal_is_one_token_read_exactly(self):
+        """A WKT literal reaches the WKT reader whole, as one token, and
+        every vertex of a many-vertex multipolygon (shell, hole, second
+        part) parses back to the float it was printed from."""
+        from geomesa_tpu.filter.ecql import _P
+
+        rng = np.random.default_rng(11)
+        a = np.linspace(0.0, 2.0 * np.pi, 1024, endpoint=False)
+        shell = np.stack([np.cos(a), np.sin(a)], 1) * rng.uniform(
+            5, 10, (1024, 1))
+        shell = np.vstack([shell, shell[:1]]).astype(np.float32)
+        hole = np.array([[0.5, 0.5], [1, 0.5], [1, 1], [0.5, 0.5]])
+        part = hole + 40.0
+
+        def ring(r):
+            return "(" + ", ".join(f"{float(x)!r} {float(y)!r}"
+                                   for x, y in r) + ")"
+
+        wkt = (f"MULTIPOLYGON (({ring(shell)}, {ring(hole)}), "
+               f"({ring(part)}))")
+        text = f"INTERSECTS(geom, {wkt}) AND count > 3"
+        toks = _P(text).toks
+        assert ("wkt", wkt) in toks and len(toks) == 10
+        f = parse_ecql(text)
+        first, second = f.children[0].geometry.polygons
+        np.testing.assert_array_equal(first.shell, shell.astype(np.float64))
+        np.testing.assert_array_equal(first.holes[0], hole)
+        np.testing.assert_array_equal(second.shell, part)
+        # a column named like a geometry type is still a column
+        assert parse_ecql("point = 5") == Compare("=", "point", 5)
+
+    @pytest.mark.parametrize("bad", [
+        "INTERSECTS(geom, POLYGON((0 0, 1 0, 1 1, 0 0))",
+        "INTERSECTS(geom, POLYGON((0 0 0, 1 0 0, 1 1 0, 0 0 0)))",
+        "INTERSECTS(geom, POLYGON((0 0; 1 0, 1 1, 0 0)))",
+        "INTERSECTS(geom, POLYGON(()))",
+        "INTERSECTS(geom, POLYGON((0 0, 1 0, 1 1, 0 0), ))",
+        "INTERSECTS(geom, POLYGON EMPTY)",
+        "INTERSECTS(geom, CIRCLE(1 2))",
+    ], ids=["unclosed", "3d", "semicolon", "empty-ring", "trailing-comma",
+            "empty", "unknown-type"])
+    def test_malformed_geometry_literal(self, bad):
+        with pytest.raises(ValueError):
+            parse_ecql(bad)
+
 
 class TestExtract:
     def test_bbox_and_interval(self):
@@ -315,4 +360,4 @@ class TestNonPointDeviceBBox:
         cols = stage_columns(batch, cols_needed)
         expect = evaluate_host(f, batch)
         np.testing.assert_array_equal(np.asarray(mask_fn(cols)), expect)
-        assert int(count_fn(cols)) == int(expect.sum())
+        assert int(count_fn(cols)[0]) == int(expect.sum())

@@ -1,0 +1,275 @@
+"""The point-in-polygon kernel (ops/pallas_scan.py) against the plain
+reference (benchmark/reference.polygon_count), in interpret mode and on
+the XLA path: zones of every edge bucket, multipart zones and holes, a
+time window whose blocks the kernel skips; its block and edge counters;
+the engine the resident count records and its counters; one compiled
+program per edge bucket."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+from geomesa_tpu import metrics
+from geomesa_tpu.features.batch import FeatureBatch
+from geomesa_tpu.features.sft import SimpleFeatureType
+from geomesa_tpu.filter import compile as compile_mod
+from geomesa_tpu.filter.compile import compile_filter
+from geomesa_tpu.filter.ecql import parse_ecql
+from geomesa_tpu.ops import pallas_scan
+from geomesa_tpu.ops.scan import stage_columns
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import reference  # noqa: E402
+
+SPEC = "fare:Float,dtg:Date,*geom:Point:srid=4326"
+SFT = SimpleFeatureType.create("t", SPEC)
+T0 = 1_420_070_400_000  # 2015-01-01
+DAY = 86_400_000
+WINDOW = "dtg DURING 2015-01-20T00:00:00Z/2015-02-03T00:00:00Z"
+W0, W1 = T0 + 19 * DAY, T0 + 33 * DAY
+
+
+def _ring(k: int, cx: float, cy: float, r0: float, r1: float) -> np.ndarray:
+    """A closed star of ``k`` edges (radii alternating r0, r1), its
+    vertices float32-representable."""
+    a = np.linspace(0.0, 2.0 * np.pi, k, endpoint=False)
+    r = np.where(np.arange(k) % 2 == 0, r0, r1)
+    ring = np.stack([cx + r * np.cos(a), cy + r * np.sin(a)], axis=1)
+    return np.vstack([ring, ring[:1]]).astype(np.float32).astype(np.float64)
+
+
+def _wkt_ring(ring) -> str:
+    return "(" + ", ".join(f"{float(x)!r} {float(y)!r}" for x, y in ring) + ")"
+
+
+def zone(n_edges: int, kind: str):
+    """(WKT, reference parts) of a zone of ``n_edges`` edges: one ring,
+    a shell with a hole, or three parts."""
+    if kind == "single":
+        parts = [[_ring(n_edges, 0.0, 0.0, 60.0, 25.0)]]
+    elif kind == "hole":
+        parts = [[_ring(n_edges - 8, 0.0, 0.0, 60.0, 25.0),
+                  _ring(8, 1.0, 2.0, 15.0, 9.0)]]
+    else:
+        k = n_edges // 3
+        parts = [[_ring(k, -100.0, 0.0, 50.0, 20.0)],
+                 [_ring(k, 0.0, 30.0, 40.0, 20.0)],
+                 [_ring(n_edges - 2 * k, 100.0, -20.0, 45.0, 30.0)]]
+    polys = ["(" + ", ".join(_wkt_ring(r) for r in rings) + ")"
+             for rings in parts]
+    if len(polys) == 1:
+        return "POLYGON " + polys[0], parts
+    return "MULTIPOLYGON (" + ", ".join(polys) + ")", parts
+
+
+def _data(n: int, seed: int):
+    """Seeded points over the globe in ascending time (so a window
+    covers a run of rows and the kernel skips the blocks outside it)."""
+    rng = np.random.default_rng(seed)
+    lon = rng.uniform(-180, 180, n).astype(np.float32)
+    lat = rng.uniform(-90, 90, n).astype(np.float32)
+    dtg = np.sort(rng.integers(T0, T0 + 60 * DAY, n))
+    batch = FeatureBatch.from_columns(SFT, {
+        "fare": rng.uniform(0, 50, n),
+        "dtg": dtg,
+        "geom": np.stack([lon, lat], axis=1).astype(np.float64),
+    })
+    return batch, reference.Table(lon, lat, dtg)
+
+
+@pytest.fixture(scope="module")
+def data():
+    return _data(6000, 26)
+
+
+def _kernel(cql: str, **kw):
+    cf = compile_filter(parse_ecql(cql), SFT)
+    assert cf.fully_on_device
+    scan = cf.pallas_scan(**kw)
+    assert scan is not None, "the polygon did not reach the kernel"
+    return cf, scan
+
+
+@pytest.mark.parametrize("kind", ["single", "hole", "multipart"])
+@pytest.mark.parametrize("n_edges", [16, 64, 65, 512, 1024])
+def test_kernel_and_xla_path_match_reference(data, n_edges, kind):
+    batch, tab = data
+    wkt, parts = zone(n_edges, kind)
+    cf, (count_fn, mask_fn) = _kernel(
+        f"INTERSECTS(geom, {wkt}) AND {WINDOW}", block_rows=32)
+    cols = stage_columns(batch, cf.device_cols)
+    want = reference.polygon_count(tab, parts, W0, W1)
+    assert want > 0
+    assert int(count_fn(cols)[0]) == want
+    mask = np.asarray(mask_fn(cols))
+    assert int(mask.sum()) == want
+    np.testing.assert_array_equal(mask, cf.host_mask(batch))
+    # the XLA path: the same filter, edges broadcast
+    assert int(np.asarray(cf.device_fn(cols)).sum()) == want
+
+
+@pytest.mark.parametrize("bucket", [16, 32, 64, 128, 256, 512, 1024, 2048,
+                                    4096])
+def test_every_edge_bucket(data, bucket):
+    """The padding edges never straddle: a polygon just over the next
+    smaller bucket and one that fills the bucket count alike."""
+    batch, tab = data
+    for n_edges in (bucket // 2 + 1, bucket):
+        wkt, parts = zone(n_edges, "single")
+        cf, (count_fn, _) = _kernel(f"INTERSECTS(geom, {wkt})")
+        out = count_fn(stage_columns(batch, cf.device_cols))
+        assert pallas_scan.padded_edges(cf.device_part) == bucket
+        assert pallas_scan.edge_bucket(n_edges) == bucket
+        assert int(out[0]) == reference.polygon_count(tab, parts, T0,
+                                                      T0 + 60 * DAY)
+
+
+def test_over_the_largest_bucket_takes_the_xla_path(data):
+    wkt, _ = zone(pallas_scan.MAX_KERNEL_EDGES + 1, "single")
+    cf = compile_filter(parse_ecql(f"INTERSECTS(geom, {wkt})"), SFT)
+    assert cf.pallas_scan() is None
+
+
+def test_blocks_tested_and_skipped_add_up(data):
+    """A window over a few of the rows' days: the kernel tests fewer
+    blocks than it has, and tested + skipped is its block count."""
+    batch, tab = data
+    wkt, parts = zone(200, "single")
+    cf, (count_fn, _) = _kernel(f"INTERSECTS(geom, {wkt}) AND {WINDOW}",
+                                block_rows=128)
+    cols = stage_columns(batch, cf.device_cols)
+    tile = 128 * pallas_scan.LANES
+    blocks = -(-len(batch) // tile) * (128 // pallas_scan.SKIP_ROWS)
+    hits, tested, skipped = np.asarray(count_fn(cols)).tolist()
+    assert hits == reference.polygon_count(tab, parts, W0, W1)
+    assert tested + skipped == blocks
+    assert 0 < tested < blocks
+
+
+def test_validity_plane_and_padding(data):
+    """Rows a validity plane marks dead never count; the grid's padding
+    rows never count."""
+    import jax.numpy as jnp
+
+    batch, tab = data
+    wkt, parts = zone(100, "hole")
+    cf, (count_fn, mask_fn) = _kernel(f"INTERSECTS(geom, {wkt})",
+                                      block_rows=32)
+    cols = stage_columns(batch, cf.device_cols)
+    live = np.arange(len(batch)) % 3 != 0
+    expect = cf.host_mask(batch) & live
+    assert int(count_fn(cols, jnp.asarray(live))[0]) == int(expect.sum())
+    np.testing.assert_array_equal(
+        np.asarray(mask_fn(cols, jnp.asarray(live))), expect)
+
+
+def _resident(n: int = 4000, cls=None, **kw):
+    from geomesa_tpu.device_cache import DeviceIndex
+    from geomesa_tpu.store.memory import MemoryDataStore
+
+    batch, tab = _data(n, 5)
+    ds = MemoryDataStore()
+    ds.create_schema("t", SPEC)
+    ds.write("t", batch)
+    return (cls or DeviceIndex)(ds, "t", **kw), tab
+
+
+def _counters():
+    return (metrics.pip_blocks.value(outcome="tested"),
+            metrics.pip_blocks.value(outcome="skipped"),
+            metrics.pip_edge_tests.value())
+
+
+def _engines():
+    return {e: metrics.pip_queries.value(engine=e)
+            for e in ("pallas", "xla", "host")}
+
+
+def test_engine_counter_and_one_program_per_bucket(monkeypatch):
+    """On a (simulated) TPU pick, a 1,024-edge zone is counted under
+    ``pallas``; zones of one edge bucket under other windows compile one
+    program (the compile ledger's signature for the bucket); a polygon
+    relation the kernel cannot run is counted under ``host``."""
+    from geomesa_tpu.ledger import COMPILES
+
+    monkeypatch.setattr(compile_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pallas_scan, "_PIP_PROGRAMS", {})
+    di, tab = _resident()
+    before = _engines()
+    wkt, parts = zone(1024, "multipart")
+    cql = f"INTERSECTS(geom, {wkt}) AND {WINDOW}"
+    assert di.count(cql) == reference.polygon_count(tab, parts, W0, W1)
+    after = _engines()
+    assert after["pallas"] - before["pallas"] == 1
+    assert after["xla"] == before["xla"] and after["host"] == before["host"]
+
+    def compiles(sig):
+        doc = COMPILES.snapshot(top=1 << 20)["by_signature"]
+        return doc.get(sig, {}).get("compiles", 0)
+
+    sig = "cache.scan:pip:e=128"
+    c0 = compiles(sig)
+    for n_edges, day in ((100, 3), (110, 9), (128, 20)):
+        wkt, parts = zone(n_edges, "single")
+        t0 = T0 + day * DAY
+        q = (f"INTERSECTS(geom, {wkt}) AND dtg DURING "
+             f"{np.datetime64(t0, 'ms')}Z/{np.datetime64(t0 + 7 * DAY, 'ms')}Z")
+        assert di.count(q) == reference.polygon_count(tab, parts, t0,
+                                                      t0 + 7 * DAY)
+    assert len([k for k in pallas_scan._PIP_PROGRAMS if "128)" in k[0]]) == 1
+    assert compiles(sig) - c0 == 1
+
+    wkt, parts = zone(64, "single")
+    before = _engines()
+    di.count(f"CROSSES(geom, {wkt})")
+    after = _engines()
+    assert after["host"] - before["host"] == 1
+    assert after["pallas"] == before["pallas"]
+
+
+def test_resident_count_moves_block_and_edge_counters(monkeypatch):
+    """A resident count on the polygon kernel moves the block counters
+    by its tested and skipped blocks, and the edge tests by the tested
+    blocks' rows times the padded edges."""
+    monkeypatch.setattr(compile_mod, "_on_tpu", lambda: True)
+    di, tab = _resident()
+    wkt, parts = zone(200, "single")
+    cql = f"INTERSECTS(geom, {wkt}) AND {WINDOW}"
+    t0, s0, e0 = _counters()
+    assert di.count(cql) == reference.polygon_count(tab, parts, W0, W1)
+    t1, s1, e1 = _counters()
+    cols = compile_filter(parse_ecql(cql), SFT).device_cols
+    br = pallas_scan._pick_block_rows(len(cols))
+    blocks = -(-di._staged_len() // (br * pallas_scan.LANES)) * (
+        br // pallas_scan.SKIP_ROWS)
+    assert (t1 - t0) + (s1 - s0) == blocks
+    assert t1 - t0 > 0
+    rows = pallas_scan.SKIP_ROWS * pallas_scan.LANES
+    assert e1 - e0 == (t1 - t0) * rows * 256
+
+
+def test_mesh_index_counts_its_xla_scan(monkeypatch):
+    """A mesh index keeps the XLA scan even where the platform picks the
+    Pallas kernel (a Pallas call is not SPMD-partitionable): the engine
+    counter says ``xla``, the entry records it, and the polygon kernel's
+    counters do not move."""
+    from geomesa_tpu.device_cache import ShardedDeviceIndex
+    from geomesa_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(compile_mod, "_on_tpu", lambda: True)
+    di, tab = _resident(cls=ShardedDeviceIndex, mesh=make_mesh(2))
+    wkt, parts = zone(300, "multipart")
+    cql = f"INTERSECTS(geom, {wkt}) AND {WINDOW}"
+    before, c0 = _engines(), _counters()
+    assert di.count(cql) == reference.polygon_count(tab, parts, W0, W1)
+    after = _engines()
+    assert after["xla"] - before["xla"] == 1
+    assert after["pallas"] == before["pallas"]
+    assert _counters() == c0
+    compiled, _, _, engine = di._compiled[repr(parse_ecql(cql))]
+    assert compiled.scan_engine == "pallas" and engine == "xla"

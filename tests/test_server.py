@@ -404,6 +404,104 @@ def test_resident_requests_move_each_stage_once(tmp_path):
         server.shutdown()
 
 
+def _scrape(url) -> dict:
+    """/metrics as {``name{labels}``: value}."""
+    _, _, body = _get(f"{url}/metrics")
+    out = {}
+    for line in body.decode().splitlines():
+        if line and not line.startswith("#"):
+            key, _, val = line.rpartition(" ")
+            out[key] = float(val)
+    return out
+
+
+def test_resident_polygon_count_parses_once_and_counts_its_kernel(
+        monkeypatch):
+    """A resident /count of INTERSECTS(zone) AND DURING through the
+    scheduler, on a (simulated) TPU pick: the CQL parse is observed once,
+    the engine counter names the polygon kernel (and the host for a
+    relation the kernel cannot run), and the kernel's tested plus
+    skipped blocks are its block count."""
+    import time
+
+    from geomesa_tpu.filter import compile as compile_mod
+    from geomesa_tpu.filter.compile import compile_filter, evaluate_host
+    from geomesa_tpu.filter.ecql import parse_ecql
+    from geomesa_tpu.ops import pallas_scan
+
+    monkeypatch.setattr(compile_mod, "_on_tpu", lambda: True)
+    ds = MemoryDataStore()
+    ds.create_schema("gdelt", SPEC)
+    n = 3000
+    rng = np.random.default_rng(29)
+    t0 = parse_instant("2020-01-01T00:00:00")
+    ds.write(
+        "gdelt",
+        {
+            "name": rng.choice(["a", "b"], n),
+            "dtg": t0 + rng.integers(0, 10**8, n),
+            "geom": np.stack(
+                [rng.uniform(-20, 20, n), rng.uniform(-20, 20, n)], axis=1
+            ),
+        },
+        fids=np.arange(n),
+    )
+    a = np.linspace(0.0, 2.0 * np.pi, 1024, endpoint=False)
+    r = np.where(np.arange(1024) % 2 == 0, 12.0, 6.0)
+    ring = np.stack([r * np.cos(a), r * np.sin(a)], axis=1).astype(
+        np.float32)
+    ring = np.vstack([ring, ring[:1]])
+    cql = ("INTERSECTS(geom, POLYGON((" + ", ".join(
+        f"{float(x)!r} {float(y)!r}" for x, y in ring) + "))) AND "
+        "dtg DURING 2020-01-01T00:00:00Z/2020-01-01T20:00:00Z")
+    server, _ = serve_background(ds, resident=True, sched=True)
+    host, port = server.server_address[:2]
+    url = f"http://{host}:{port}"
+    try:
+        _get(f"{url}/count/gdelt?cql=INCLUDE")  # the first query stages
+        before = _scrape(url)
+        status, _, body = _get(
+            f"{url}/count/gdelt?cql={urllib.request.quote(cql)}")
+        assert status == 200
+        f = parse_ecql(cql)
+        batch = ds.query("gdelt", "INCLUDE").batch
+        assert json.loads(body)["count"] == int(
+            evaluate_host(f, batch).sum())
+        time.sleep(0.2)
+        after = _scrape(url)
+
+        def moved(key):
+            return after.get(key, 0.0) - before.get(key, 0.0)
+
+        assert moved('geomesa_stage_seconds_count{stage="cql.parse"}') == 1
+        assert moved('geomesa_pip_queries_total{engine="pallas"}') == 1
+        assert moved('geomesa_pip_queries_total{engine="xla"}') == 0
+        # the served index pads its buffers: the kernel walks them all
+        di = server.RequestHandlerClass._resident_cache["gdelt"]
+        staged = di._staged_len()
+        rows = pallas_scan.SKIP_ROWS
+        cols = compile_filter(f, ds.get_schema("gdelt")).device_cols
+        br = pallas_scan._pick_block_rows(len(cols))
+        blocks = -(-staged // (br * pallas_scan.LANES)) * (br // rows)
+        tested = moved('geomesa_pip_blocks_total{outcome="tested"}')
+        assert 0 < tested < blocks
+        assert tested + moved(
+            'geomesa_pip_blocks_total{outcome="skipped"}') == blocks
+        assert moved("geomesa_pip_edge_tests_total") == (
+            tested * rows * pallas_scan.LANES * 1024)
+        # a relation the kernel cannot run is tested on the host
+        crosses = cql.replace("INTERSECTS(", "CROSSES(", 1)
+        status, _, _ = _get(
+            f"{url}/count/gdelt?cql={urllib.request.quote(crosses)}")
+        assert status == 200
+        time.sleep(0.2)
+        host = _scrape(url)
+        assert host.get('geomesa_pip_queries_total{engine="host"}', 0.0) \
+            - after.get('geomesa_pip_queries_total{engine="host"}', 0.0) == 1
+    finally:
+        server.shutdown()
+
+
 def test_server_auths_param_resident_and_store():
     """auths=A,B serves labeled rows from the resident fast path; absent
     auths fail closed. Store-path (non-resident) behavior is identical."""

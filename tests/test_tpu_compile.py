@@ -114,6 +114,55 @@ def test_pallas_filter_scan_compiles(one_chip, cql, which, valid):
     _assert_kernel(_compile(fn, *args))
 
 
+def _zone(n_edges: int, parts: int = 1) -> str:
+    """A star-shaped zone of ``n_edges`` edges over NYC, split in
+    ``parts`` parts."""
+    rings = []
+    for p in range(parts):
+        k = n_edges // parts
+        a = np.linspace(0.0, 2 * np.pi, k, endpoint=False)
+        r = np.where(np.arange(k) % 2 == 0, 0.02, 0.008)
+        cx = -73.98 + 0.05 * p
+        ring = np.stack([cx + r * np.cos(a), 40.75 + r * np.sin(a)], 1)
+        ring = np.vstack([ring, ring[:1]]).astype(np.float32)
+        rings.append("((" + ", ".join(
+            f"{float(x)!r} {float(y)!r}" for x, y in ring) + "))")
+    return "MULTIPOLYGON (" + ", ".join(rings) + ")"
+
+
+@pytest.mark.parametrize("which", ["count", "mask"])
+@pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
+@pytest.mark.parametrize("n_edges", [1024, 4096])
+def test_polygon_kernel_compiles(one_chip, which, x64, n_edges):
+    """The point-in-polygon kernel with its edge table, envelope and
+    window as SMEM operands, at the largest zone of the taxi cell and at
+    the largest edge bucket, over 2^25 rows (the cell's size)."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomesa_tpu.features.sft import SimpleFeatureType
+    from geomesa_tpu.filter.compile import compile_filter
+    from geomesa_tpu.filter.ecql import parse_ecql
+    from geomesa_tpu.ops.pallas_scan import build_pallas_scan
+
+    sft = SimpleFeatureType.create(
+        "trips", "fare:Double,dtg:Date,*geom:Point:srid=4326"
+    )
+    cql = (f"INTERSECTS(geom, {_zone(n_edges, 2)}) AND dtg DURING "
+           "2015-01-10T00:00:00Z/2015-01-17T00:00:00Z")
+    cf = compile_filter(parse_ecql(cql), sft)
+    count_fn, mask_fn, cols = build_pallas_scan(
+        cf.device_part, sft, interpret=False
+    )
+    dtypes = {"geom__x": jnp.float32, "geom__y": jnp.float32,
+              "dtg__hi": jnp.int32, "dtg__lo": jnp.uint32}
+    coldict = {c: _spec(one_chip, (1 << 25,), dtypes[c]) for c in cols}
+    fn = mask_fn if which == "mask" else count_fn
+    with jax.enable_x64(x64):
+        compiled = _compile(fn, coldict)
+    _assert_kernel(compiled)
+
+
 @pytest.mark.parametrize("which", ["count", "mask"])
 @pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
 def test_dimplane_scan_compiles(one_chip, which, x64):

@@ -87,8 +87,9 @@ def _measure(chain, inputs, args, k: int, n: int, bytes_per_row: int,
 
 
 def _hits(count_fn):
-    """A Pallas count ([hits, blocks tested, blocks skipped]) as a
-    jittable count of hits."""
+    """A Pallas count ([hits, blocks tested, blocks skipped], and the
+    polygon kernel's tiles walked and skipped) as a jittable count of
+    hits."""
     return lambda c: count_fn(c)[0]
 
 

@@ -1053,7 +1053,7 @@ class DeviceIndex:
         plane as one more input; a mesh index keeps the XLA-fused scan,
         which jit partitions across the shards (a Pallas call is not
         SPMD-partitionable). A count on the polygon kernel moves the
-        point-in-polygon block and edge-test counters."""
+        point-in-polygon tile, block and edge-test counters."""
         import jax
 
         from geomesa_tpu import metrics
@@ -1102,12 +1102,15 @@ class DeviceIndex:
             out = dispatch(cols)
 
             def fetch() -> int:
-                hits, tested, skipped = _fetch(np.asarray, out).tolist()
+                got = _fetch(np.asarray, out).tolist()
                 if edge_tests:
+                    _, tested, skipped, walked, dead = got
                     metrics.pip_blocks.inc_key(metrics.PIP_TESTED, tested)
                     metrics.pip_blocks.inc_key(metrics.PIP_SKIPPED, skipped)
                     metrics.pip_edge_tests.inc(tested * edge_tests)
-                return hits
+                    metrics.pip_tiles.inc_key(metrics.PIP_WALKED, walked)
+                    metrics.pip_tiles.inc_key(metrics.PIP_TILE_SKIPPED, dead)
+                return got[0]
 
             return fetch
 

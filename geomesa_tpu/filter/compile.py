@@ -298,9 +298,9 @@ def _on_tpu() -> bool:
 
 def xla_counts(mask):
     """A count fn's result from an XLA-fused mask: int32 [hits, 0, 0],
-    the shape of the Pallas kernels' [hits, row blocks tested, row
-    blocks skipped] (ops/pallas_scan.build_pallas_scan); the XLA scan
-    has no blocks."""
+    the shape of the bbox kernel's [hits, row blocks tested, row blocks
+    skipped] (ops/pallas_scan.build_pallas_scan); the XLA scan has no
+    blocks."""
     import jax.numpy as jnp
 
     zero = jnp.int32(0)
@@ -529,7 +529,7 @@ class CompiledFilter:
     def pallas_scan(self, **kw):
         """(count_fn, mask_fn) jitted Pallas TPU kernels for the device
         part (count_fn gives int32 [hits, blocks tested, blocks
-        skipped]), or None when the filter can't be tiled (callers use
+        skipped, ...]), or None when the filter can't be tiled (callers use
         device_fn). Cached per CompiledFilter and option set."""
         if not hasattr(self, "_pallas"):
             self._pallas = {}

@@ -524,6 +524,14 @@ pip_blocks = REGISTRY.counter(
 )
 PIP_TESTED = pip_blocks.labels(outcome="tested")
 PIP_SKIPPED = pip_blocks.labels(outcome="skipped")
+# its grid tiles: skipped where no row of the tile passes the pretests,
+# else walked block by block
+pip_tiles = REGISTRY.counter(
+    "geomesa_pip_tiles_total",
+    "polygon kernel grid tiles, by outcome (walked|skipped)",
+)
+PIP_WALKED = pip_tiles.labels(outcome="walked")
+PIP_TILE_SKIPPED = pip_tiles.labels(outcome="skipped")
 
 # served-path stages (tracing.STAGES): every span of a declared stage
 # observes its wall time and adds its thread CPU time here, whether or

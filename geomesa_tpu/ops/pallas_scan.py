@@ -21,7 +21,8 @@ A filter with a point-in-polygon conjunct takes the polygon kernel
 the DURING/BBOX bounds ride in SMEM as runtime operands, so one compiled
 program serves every filter of the same shape and edge bucket, and the
 edge loop runs only on the row blocks where some row passes the cheap
-exact pretests the predicate implies (the envelope, the time window).
+exact pretests the predicate implies (the envelope, the time window);
+a grid tile where no row passes them skips its block walk.
 
 On CPU jax (tests / CI) the kernel runs in interpret mode, so the whole
 suite exercises the identical kernel code without a TPU.
@@ -394,10 +395,13 @@ def _pip_programs(plan: _TilePlan, cols: list, br: int, interpret: bool):
 
 def _pip_kernels(cheap, full, cols: list, br: int, interpret: bool):
     """Jitted ``pip_count(coldict, fq, iq, uq, valid)`` -> int32
-    [hits, blocks tested, blocks skipped] and ``pip_mask(...)`` -> bool
-    mask. Each grid
-    step walks its tile in blocks of ``SKIP_ROWS`` x 128; a block whose
-    live rows all fail ``cheap`` skips the predicate (its edge loop)."""
+    [hits, blocks tested, blocks skipped, tiles walked, tiles skipped]
+    and ``pip_mask(...)`` -> bool mask. Each grid step first tests its
+    whole tile: where no live row passes ``cheap``, the tile is skipped
+    in one scalar branch. Otherwise it walks the tile in blocks of
+    ``SKIP_ROWS`` x 128; a block whose live rows all fail ``cheap``
+    skips the predicate (its edge loop). A filter with no pretest walks
+    every tile and tests every block."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -409,35 +413,51 @@ def _pip_kernels(cheap, full, cols: list, br: int, interpret: bool):
     def _any(m):
         return jnp.max(m.astype(jnp.int32)) > jnp.int32(0)
 
-    def _walk(n: int, has_valid: bool, on_block):
+    def _walk(n: int, has_valid: bool, on_block, on_dead_tile):
         """The kernel body over one tile: ``on_block(r0, sub, pre, q)``
         for each block of ``SKIP_ROWS`` sublanes, ``pre`` the live rows
-        that pass the pretest."""
+        that pass the pretest; or ``on_dead_tile()`` alone where no row
+        of the tile passes it."""
 
         def body(fq, iq, uq, in_refs):
             q = (fq, iq, uq)
             i = pl.program_id(0)
 
-            def block(b, carry):
-                # int32 literals throughout: see _crossing_parity
-                r0 = pl.multiple_of(b * jnp.int32(SKIP_ROWS), SKIP_ROWS)
-                sub = {c: r[pl.ds(r0, SKIP_ROWS), :] for c, r in zip(cols, in_refs)}
+            def pretest(r0, rows: int):
+                """(the columns' rows [r0, r0 + rows), the live ones of
+                them that pass the pretest); the whole tile where ``r0``
+                is None."""
+                at = slice(None) if r0 is None else pl.ds(r0, rows)
+                sub = {c: r[at, :] for c, r in zip(cols, in_refs)}
+                row = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0)
                 idx = (
                     i * jnp.int32(br * LANES)
-                    + (r0 + jax.lax.broadcasted_iota(
-                        jnp.int32, (SKIP_ROWS, LANES), 0)) * jnp.int32(LANES)
-                    + jax.lax.broadcasted_iota(jnp.int32, (SKIP_ROWS, LANES), 1)
+                    + (row if r0 is None else r0 + row) * jnp.int32(LANES)
+                    + jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
                 )
                 pre = idx < jnp.int32(n)
                 if has_valid:
-                    pre = pre & (in_refs[-1][pl.ds(r0, SKIP_ROWS), :]
-                                 != jnp.int8(0))
+                    pre = pre & (in_refs[-1][at, :] != jnp.int8(0))
                 if cheap is not None:
                     pre = pre & cheap(sub, q)
-                on_block(r0, sub, pre, q)
+                return sub, pre
+
+            def block(b, carry):
+                # int32 literals throughout: see _crossing_parity
+                r0 = pl.multiple_of(b * jnp.int32(SKIP_ROWS), SKIP_ROWS)
+                on_block(r0, *pretest(r0, SKIP_ROWS), q)
                 return carry
 
-            jax.lax.fori_loop(_zero(), jnp.int32(br // SKIP_ROWS), block, _zero())
+            def walk():
+                jax.lax.fori_loop(_zero(), jnp.int32(br // SKIP_ROWS), block,
+                                  _zero())
+
+            if cheap is None:
+                walk()
+                return
+            live = _any(pretest(None, br)[1])
+            pl.when(live)(walk)
+            pl.when(jnp.logical_not(live))(on_dead_tile)
 
         return body
 
@@ -454,12 +474,13 @@ def _pip_kernels(cheap, full, cols: list, br: int, interpret: bool):
         n, grid, mats = _tile_mats(cols, br, coldict, valid)
 
         def kernel(fq_ref, iq_ref, uq_ref, *refs):
-            in_refs, (hits_ref, tested_ref) = refs[:-2], refs[-2:]
+            in_refs, (hits_ref, tested_ref, dead_ref) = refs[:-3], refs[-3:]
 
             @pl.when(pl.program_id(0) == 0)
             def _():
                 hits_ref[...] = jnp.zeros((1, LANES), jnp.int32)
                 tested_ref[...] = jnp.zeros((1, LANES), jnp.int32)
+                dead_ref[...] = jnp.zeros((1, LANES), jnp.int32)
 
             def on_block(r0, sub, pre, q):
                 @pl.when(_any(pre))
@@ -470,20 +491,24 @@ def _pip_kernels(cheap, full, cols: list, br: int, interpret: bool):
                         keepdims=True)
                     tested_ref[...] += jnp.int32(1)
 
-            _walk(n, valid is not None, on_block)(
+            def on_dead_tile():
+                dead_ref[...] += jnp.int32(1)
+
+            _walk(n, valid is not None, on_block, on_dead_tile)(
                 fq_ref, iq_ref, uq_ref, in_refs)
 
         acc = pl.BlockSpec((1, LANES), lambda i, *_: (_zero(), _zero()))
-        hits, tested = pl.pallas_call(
+        hits, tested, dead = pl.pallas_call(
             kernel,
-            grid_spec=_specs(len(mats), [acc, acc], grid),
-            out_shape=[jax.ShapeDtypeStruct((1, LANES), jnp.int32)] * 2,
+            grid_spec=_specs(len(mats), [acc, acc, acc], grid),
+            out_shape=[jax.ShapeDtypeStruct((1, LANES), jnp.int32)] * 3,
             interpret=interpret,
             name="pip_count",
         )(fq, iq, uq, *mats)
         blocks = jnp.int32(grid * (br // SKIP_ROWS))
         return jnp.stack([jnp.sum(hits, dtype=jnp.int32), tested[0, 0],
-                          blocks - tested[0, 0]])
+                          blocks - tested[0, 0], jnp.int32(grid) - dead[0, 0],
+                          dead[0, 0]])
 
     def pip_mask(coldict, fq, iq, uq, valid=None):
         n, grid, mats = _tile_mats(cols, br, coldict, valid)
@@ -500,7 +525,10 @@ def _pip_kernels(cheap, full, cols: list, br: int, interpret: bool):
                     out_ref[pl.ds(r0, SKIP_ROWS), :] = (
                         pre & full(sub, q)).astype(jnp.int8)
 
-            _walk(n, valid is not None, on_block)(
+            def on_dead_tile():
+                out_ref[...] = jnp.zeros((br, LANES), jnp.int8)
+
+            _walk(n, valid is not None, on_block, on_dead_tile)(
                 fq_ref, iq_ref, uq_ref, in_refs)
 
         m = pl.pallas_call(
@@ -571,8 +599,9 @@ def build_pallas_scan(
     Returns ``(count_fn, mask_fn, cols)`` where each fn takes a dict of
     staged 1-D device columns (see ops/scan.stage_columns) and returns
     int32 [hits, row blocks tested, row blocks skipped] / the bool mask
-    for the whole array: the polygon kernel skips blocks (``SKIP_ROWS``
-    x 128 rows), the bbox kernel tests every tile of its grid. Either fn
+    for the whole array: the polygon kernel skips whole tiles and blocks
+    (``SKIP_ROWS`` x 128 rows), and its count adds [tiles walked, tiles
+    skipped]; the bbox kernel tests every tile of its grid. Either fn
     takes an optional bool ``valid`` plane (the padded buffers of a
     streaming resident index): rows where it is False never match. Raises
     PallasUnsupported when the filter can't be tiled; callers fall back to

@@ -1,9 +1,9 @@
 """The point-in-polygon kernel (ops/pallas_scan.py) against the plain
 reference (benchmark/reference.polygon_count), in interpret mode and on
 the XLA path: zones of every edge bucket, multipart zones and holes, a
-time window whose blocks the kernel skips; its block and edge counters;
-the engine the resident count records and its counters; one compiled
-program per edge bucket."""
+time window whose blocks and whole tiles the kernel skips; its tile,
+block and edge counters; the engine the resident count records and its
+counters; one compiled program per edge bucket."""
 
 import os
 import sys
@@ -145,10 +145,12 @@ def test_blocks_tested_and_skipped_add_up(data):
     cols = stage_columns(batch, cf.device_cols)
     tile = 128 * pallas_scan.LANES
     blocks = -(-len(batch) // tile) * (128 // pallas_scan.SKIP_ROWS)
-    hits, tested, skipped = np.asarray(count_fn(cols)).tolist()
+    hits, tested, skipped, walked, dead = np.asarray(
+        count_fn(cols)).tolist()
     assert hits == reference.polygon_count(tab, parts, W0, W1)
     assert tested + skipped == blocks
     assert 0 < tested < blocks
+    assert (walked, dead) == (1, 0)
 
 
 def test_validity_plane_and_padding(data):
@@ -166,6 +168,142 @@ def test_validity_plane_and_padding(data):
     assert int(count_fn(cols, jnp.asarray(live))[0]) == int(expect.sum())
     np.testing.assert_array_equal(
         np.asarray(mask_fn(cols, jnp.asarray(live))), expect)
+
+
+#: rows of a grid tile at ``block_rows=32``, and rows over about ten of
+#: them, the last one partial (the grid's padding tail)
+TILE = 32 * pallas_scan.LANES
+N_TILED = 10 * TILE - 1000
+
+
+def _iso(ms: int) -> str:
+    return f"{np.datetime64(int(ms), 'ms')}Z"
+
+
+def _during(t0: int, t1: int) -> str:
+    return f"dtg DURING {_iso(t0)}/{_iso(t1)}"
+
+
+def _minutes(n: int, seed: int):
+    """Seeded points over the globe, a minute apart from 2015-01-01: a
+    window of a few days covers a run of whole tiles."""
+    rng = np.random.default_rng(seed)
+    lon = rng.uniform(-180, 180, n).astype(np.float32)
+    lat = rng.uniform(-90, 90, n).astype(np.float32)
+    dtg = T0 + np.arange(n, dtype=np.int64) * 60_000
+    return lon, lat, dtg, rng.uniform(0, 50, n)
+
+
+def _table(lon, lat, dtg, fare):
+    batch = FeatureBatch.from_columns(SFT, {
+        "fare": fare, "dtg": dtg,
+        "geom": np.stack([lon, lat], axis=1).astype(np.float64)})
+    return batch, reference.Table(lon, lat, dtg)
+
+
+@pytest.fixture(scope="module")
+def tiled():
+    return _table(*_minutes(N_TILED, 27))
+
+
+def _tiles(count_fn, cols, br: int, valid=None):
+    """(hits, blocks tested, tiles walked, tiles skipped) of a count,
+    checking that blocks and tiles add up to the grid's."""
+    grid = -(-len(next(iter(cols.values()))) // (br * pallas_scan.LANES))
+    hits, tested, skipped, walked, dead = np.asarray(
+        count_fn(cols, valid)).tolist()
+    assert tested + skipped == grid * (br // pallas_scan.SKIP_ROWS)
+    assert walked + dead == grid
+    return hits, tested, walked, dead
+
+
+@pytest.mark.parametrize("br", [32, 64])
+@pytest.mark.parametrize("days,dead_tiles", [((5, 12), True),
+                                             ((-1, 40), False)],
+                         ids=["window-kills-tiles", "window-covers-all"])
+def test_tile_pass_skips_only_dead_tiles(tiled, br, days, dead_tiles):
+    """A window over a few of the rows' days skips the tiles outside it
+    and counts as the reference does; one over every row skips none."""
+    batch, tab = tiled
+    wkt, parts = zone(200, "single")
+    t0, t1 = T0 + days[0] * DAY, T0 + days[1] * DAY
+    cf, (count_fn, mask_fn) = _kernel(
+        f"INTERSECTS(geom, {wkt}) AND {_during(t0, t1)}", block_rows=br)
+    cols = stage_columns(batch, cf.device_cols)
+    want = reference.polygon_count(tab, parts, t0, t1)
+    assert want > 0
+    hits, tested, walked, dead = _tiles(count_fn, cols, br)
+    assert hits == want
+    np.testing.assert_array_equal(np.asarray(mask_fn(cols)),
+                                  cf.host_mask(batch))
+    assert (dead > 0) == dead_tiles
+    assert 0 < tested <= walked * (br // pallas_scan.SKIP_ROWS)
+
+
+@pytest.mark.parametrize("br", [32, 64])
+@pytest.mark.parametrize("row", [3 * 64 * pallas_scan.LANES - 1, N_TILED - 1],
+                         ids=["tile-end", "last-live-row"])
+def test_tile_with_one_passing_last_row_is_walked(br, row):
+    """The only row that passes the pretest is the last live row of its
+    tile (a full tile's, or the one before the padding tail): the tile
+    is walked and the row counted."""
+    lon, lat, dtg, fare = _minutes(N_TILED, 28)
+    lon[row], lat[row] = 0.0, 0.0  # inside the zone
+    batch, tab = _table(lon, lat, dtg, fare)
+    wkt, parts = zone(200, "single")
+    t = int(dtg[row])
+    cf, (count_fn, mask_fn) = _kernel(
+        f"INTERSECTS(geom, {wkt}) AND {_during(t, t)}", block_rows=br)
+    cols = stage_columns(batch, cf.device_cols)
+    assert reference.polygon_count(tab, parts, t, t) == 1
+    hits, tested, walked, dead = _tiles(count_fn, cols, br)
+    assert (hits, tested, walked) == (1, 1, 1)
+    assert np.flatnonzero(np.asarray(mask_fn(cols))).tolist() == [row]
+
+
+def test_dead_tile_and_padding_tail_never_count(tiled):
+    """A validity plane that marks a whole tile dead skips it; the
+    grid's padding rows (zeros: the zone's centre, at 1970-01-01) pass
+    a window from 1969 and still never count."""
+    import jax.numpy as jnp
+
+    batch, tab = tiled
+    wkt, parts = zone(200, "single")
+    cf, (count_fn, mask_fn) = _kernel(
+        f"INTERSECTS(geom, {wkt}) AND "
+        "dtg DURING 1969-12-31T00:00:00Z/2016-01-01T00:00:00Z",
+        block_rows=32)
+    cols = stage_columns(batch, cf.device_cols)
+    live = np.ones(len(batch), bool)
+    live[2 * TILE: 3 * TILE] = False
+    live[5 * TILE + 7] = False
+    expect = cf.host_mask(batch) & live
+    assert 0 < int(expect.sum()) < int(cf.host_mask(batch).sum())
+    hits, _, walked, dead = _tiles(count_fn, cols, 32, jnp.asarray(live))
+    assert hits == int(expect.sum())
+    assert dead == 1
+    np.testing.assert_array_equal(
+        np.asarray(mask_fn(cols, jnp.asarray(live))), expect)
+
+
+@pytest.mark.parametrize("form", ["NOT INTERSECTS(geom, {z})",
+                                  "NOT INTERSECTS(geom, {z}) OR fare > 10",
+                                  "{w} OR NOT INTERSECTS(geom, {z})"],
+                         ids=["not", "or-fare", "or-window"])
+def test_filter_without_pretest_walks_every_tile(tiled, form):
+    """A Not, or an Or with a branch that has no pretest: no tile pass,
+    every tile is walked (even where a window would have killed tiles)
+    and the count is the host's."""
+    batch, _ = tiled
+    wkt, _ = zone(200, "single")
+    cql = form.format(z=wkt, w=_during(T0 + 5 * DAY, T0 + 12 * DAY))
+    cf, (count_fn, mask_fn) = _kernel(cql, block_rows=32)
+    cols = stage_columns(batch, cf.device_cols)
+    want = cf.host_mask(batch)
+    hits, tested, walked, dead = _tiles(count_fn, cols, 32)
+    assert hits == int(want.sum())
+    assert dead == 0 and walked == tested == -(-len(batch) // TILE)
+    np.testing.assert_array_equal(np.asarray(mask_fn(cols)), want)
 
 
 def _resident(n: int = 4000, cls=None, **kw):
@@ -251,6 +389,29 @@ def test_resident_count_moves_block_and_edge_counters(monkeypatch):
     assert t1 - t0 > 0
     rows = pallas_scan.SKIP_ROWS * pallas_scan.LANES
     assert e1 - e0 == (t1 - t0) * rows * 256
+
+
+@pytest.mark.parametrize("day,walked", [(19, 1), (400, 0)],
+                         ids=["window-in-data", "window-past-data"])
+def test_resident_count_moves_tile_counters(monkeypatch, day, walked):
+    """A resident count on the polygon kernel moves the tile counter by
+    its one tile: walked where the window holds rows, else skipped with
+    every one of its blocks."""
+    monkeypatch.setattr(compile_mod, "_on_tpu", lambda: True)
+    di, tab = _resident()
+    wkt, parts = zone(200, "single")
+    t0, t1 = T0 + day * DAY, T0 + (day + 14) * DAY
+    cql = f"INTERSECTS(geom, {wkt}) AND {_during(t0, t1)}"
+
+    def tiles():
+        return (metrics.pip_tiles.value(outcome="walked"),
+                metrics.pip_tiles.value(outcome="skipped"))
+
+    (w0, s0), (b0, _, _) = tiles(), _counters()
+    assert di.count(cql) == reference.polygon_count(tab, parts, t0, t1)
+    (w1, s1), (b1, _, _) = tiles(), _counters()
+    assert (w1 - w0, s1 - s0) == (walked, 1 - walked)
+    assert (b1 - b0 > 0) == bool(walked)
 
 
 def test_mesh_index_counts_its_xla_scan(monkeypatch):

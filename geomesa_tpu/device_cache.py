@@ -13,6 +13,7 @@ layer's listener).
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial, wraps
 
 import numpy as np
@@ -226,6 +227,64 @@ def _scan_scoped(fn):
     return wrapped
 
 
+def _snapshot(fn):
+    """One resident read under the index's snapshot: ``self._lock``
+    (appends, evictions and restages swap or donate the planes under
+    it), then the index's ``_read_scope`` hook inside it. Every public
+    read of :class:`DeviceIndex` enters here once: ``count`` through
+    ``_launch_count``, so its fetch waits outside the lock; the others
+    for their whole call. A read nested in another (``query`` ->
+    ``mask``) re-enters the re-entrant lock."""
+    op = fn.__name__.lstrip("_")
+
+    @wraps(fn)
+    def read(self, *args, **kwargs):
+        with self._lock, self._read_scope(op):
+            return fn(self, *args, **kwargs)
+
+    return read
+
+
+# donated padded-buffer slot-writes, keyed by (delta rows, plane names)
+_SLOT_WRITES: dict = {}
+
+
+def _slot_write(pad: int, names: tuple):
+    """The donated jit ``(cols, valid, delta, n, m) -> (cols, valid)``:
+    writes a ``pad``-row delta into the fixed-capacity planes at row
+    ``n`` and marks its first ``m`` rows live in the validity plane."""
+    key = (pad, names)
+    write = _SLOT_WRITES.get(key)
+    if write is None:
+        import jax
+        import jax.numpy as jnp
+
+        def _write(cols, valid, delta, n, m):
+            out = {
+                k: jax.lax.dynamic_update_slice_in_dim(
+                    buf, delta[k].astype(buf.dtype), n, 0
+                )
+                for k, buf in cols.items()
+            }
+            live = jnp.arange(pad) < m
+            return out, jax.lax.dynamic_update_slice_in_dim(valid, live, n, 0)
+
+        write = _SLOT_WRITES[key] = jax.jit(_write, donate_argnums=(0, 1))
+    return write
+
+
+def _pad_rows(cols: dict, rows: int) -> dict:
+    """Every plane zero-padded at its tail to ``rows`` rows."""
+    import jax.numpy as jnp
+
+    return {
+        k: jnp.concatenate([v, jnp.zeros(rows - v.shape[0], v.dtype)])
+        if rows > v.shape[0]
+        else v
+        for k, v in cols.items()
+    }
+
+
 class DeviceIndex:
     """Resident scan cache over one store type.
 
@@ -253,10 +312,31 @@ class DeviceIndex:
     means labeled rows are hidden — fail closed, the store semantics.
     If the vocabulary overflows the cap, labeled rows are dropped from
     the resident copy (served by the store path only) with a warning.
+
+    Read discipline, declared once here for every subclass: each public
+    read enters ``_snapshot``, which holds ``self._lock`` -- the base
+    index never mutates its planes in place, so its lock is a no-op;
+    the streaming and mesh indexes set a checked re-entrant lock that
+    their mutations take too -- and the ``_read_scope`` hook inside it.
+    ``count`` holds the snapshot for its launch alone: the fetch reads
+    only the launched output, which a later donation cannot touch.
+
+    Padded buffers (both subclasses): fixed-capacity planes, a device
+    validity plane (``_valid``) masking the slots past the live rows,
+    one donated slot-write per delta (``_write_delta``), and a host
+    mirror kept as parts and concatenated at its next read
+    (``_host_rows``).
     """
+
+    #: the snapshot every public read holds (``_snapshot``): reusable,
+    #: re-entrant, and a no-op for an index that never mutates in place
+    _lock = contextlib.nullcontext()
 
     #: distinct visibility expressions the resident cache will track
     VIS_VOCAB_MAX = 4096
+
+    #: smallest padded-buffer delta (rows); tiny batches pad up to this
+    MIN_DELTA_ROWS = 256
 
     #: exact filters and density grids may run on Pallas kernels: a
     #: single device only, since a Mosaic call is not SPMD-partitionable
@@ -292,7 +372,10 @@ class DeviceIndex:
         self._dim_mode = False
         self._bt_base = None  # bin_base the bt plane is packed around
         self._dim_kernels: dict = {}  # R bucket -> (count_fn, mask_fn)
-        self._host_batch = None
+        self._host_batch = None  # the mirror's concat (None: stale)
+        self._host_parts: list = []  # the mirror's staged batches
+        self._n = 0  # staged rows (mirror length; may exceed live rows)
+        self._valid = None  # device validity plane; None = all live
         self._cols = None
         self._compiled: dict = {}
         self._texts: dict = {}  # CQL text -> (filter, _compiled key)
@@ -777,13 +860,10 @@ class DeviceIndex:
         with span("cache.stage", type=self.type_name, rows_hint=hint), \
                 ledger.compile_scope("cache.stage"):
             res = self.store.query(self.type_name, _staging_query())
-            self._bin_range = None
-            self._bt_base = None
-            self._visid_np = None
-            self._host_batch, self._cols = self._stage_checked(res.batch)
+            self._cols = self._stage_full(res.batch)
 
     def __len__(self) -> int:
-        return len(self._host_batch)
+        return self._n
 
     def refresh_delta(self, batch) -> str:
         """Incrementally fold freshly appended rows into the resident
@@ -802,8 +882,9 @@ class DeviceIndex:
 
     @property
     def nbytes(self) -> int:
-        """Resident device bytes."""
-        return int(sum(v.nbytes for v in self._cols.values()))
+        """Resident device bytes (planes and validity plane)."""
+        valid = 0 if self._valid is None else self._valid.nbytes
+        return int(sum(v.nbytes for v in self._cols.values()) + valid)
 
     def attach_live(self, live_store):
         """Refresh on every applied live-layer change (coarse; the
@@ -1020,11 +1101,55 @@ class DeviceIndex:
         hv = self._host_valid()
         return (m & hv) if hv is not None else m
 
-    # -- subclass hooks ----------------------------------------------------
+    # -- host mirror, padded-buffer deltas, read hooks ---------------------
+
+    def _stage_full(self, batch) -> dict:
+        """Full (re)stage: stage ``batch`` afresh (``_stage_checked``)
+        and install what it kept as the whole host mirror. Returns the
+        staged planes."""
+        self._bin_range = None
+        self._bt_base = None
+        self._visid_np = None
+        batch, cols = self._stage_checked(batch)
+        self._host_parts = [batch]
+        self._host_batch = batch
+        self._n = len(batch)
+        return cols
 
     def _host_rows(self):
-        """Host mirror aligned row-for-row with the device columns."""
+        """Host mirror aligned row-for-row with the device columns:
+        deltas collect as parts and pay ONE concat at the next host-side
+        read instead of one per append."""
+        if self._host_batch is None:
+            from geomesa_tpu.features.batch import FeatureBatch
+
+            self._host_batch = FeatureBatch.concat(self._host_parts)
+            self._host_parts = [self._host_batch]
         return self._host_batch
+
+    def _delta_pad(self, m: int) -> int:
+        """Rows an ``m``-row delta pads to (a bounded set of shapes)."""
+        return max(_next_pow2(m), self.MIN_DELTA_ROWS)
+
+    def _write_delta(self, batch, delta: dict, pad: int) -> bool:
+        """Write a batch's staged ``delta`` planes into the padded
+        planes at row ``_n`` (one donated :func:`_slot_write` launch
+        that also marks its rows live) and append it to the host
+        mirror. The caller has checked that ``pad`` rows fit. False,
+        with nothing written, when the delta's planes differ from the
+        buffers' (the first labeled rows bring a plane with no slot):
+        the caller restages."""
+        if set(delta) != set(self._cols):
+            return False
+        m = len(batch)
+        write = _slot_write(pad, tuple(sorted(delta)))
+        self._cols, self._valid = write(
+            self._cols, self._valid, _pad_rows(delta, pad), self._n, m
+        )
+        self._host_parts.append(batch)
+        self._host_batch = None
+        self._n += m
+        return True
 
     def _host_valid(self) -> "np.ndarray | None":
         """Host-side validity over the mirror rows; None = all live."""
@@ -1032,11 +1157,16 @@ class DeviceIndex:
 
     def _device_valid(self):
         """Device validity plane over staged rows; None = all live."""
-        return None
+        return self._valid
 
     def _staged_len(self) -> int:
         """Rows staged on device (mirror length; may exceed live rows)."""
-        return len(self._host_batch)
+        return self._n
+
+    def _read_scope(self, op: str):
+        """Entered by every public read inside its snapshot
+        (``_snapshot``; ``op`` names the read): a no-op here."""
+        return contextlib.nullcontext()
 
     def _make_scan_fns(self, compiled):
         """(count_fn, mask_fn, engine) over the resident column subset:
@@ -1065,31 +1195,25 @@ class DeviceIndex:
             engine = "xla"
             plain_mask = jax.jit(compiled.device_fn)
             plain_count = jax.jit(lambda c: xla_counts(compiled.device_fn(c)))
-        if self._device_valid() is None and type(self) is DeviceIndex:
-            # the base cache never pads: skip the per-call dispatch
-            dispatch, mask_fn = plain_count, plain_mask
+        if engine == "pallas":
+            count_jit, mask_jit = plain_count, plain_mask
         else:
-            if engine == "pallas":
-                count_jit, mask_jit = plain_count, plain_mask
-            else:
-                mask_jit = jax.jit(
-                    lambda cols, valid: compiled.device_fn(cols) & valid
-                )
-                count_jit = jax.jit(
-                    lambda cols, valid: xla_counts(
-                        compiled.device_fn(cols) & valid)
-                )
+            mask_jit = jax.jit(
+                lambda cols, valid: compiled.device_fn(cols) & valid
+            )
+            count_jit = jax.jit(
+                lambda cols, valid: xla_counts(
+                    compiled.device_fn(cols) & valid)
+            )
 
-            def dispatch(cols):
-                dv = self._device_valid()
-                return count_jit(cols, dv) if dv is not None else \
-                    plain_count(cols)
+        def dispatch(cols):
+            dv = self._device_valid()
+            return count_jit(cols, dv) if dv is not None else \
+                plain_count(cols)
 
-            def mask_fn(cols):
-                dv = self._device_valid()
-                return mask_jit(cols, dv) if dv is not None else plain_mask(
-                    cols
-                )
+        def mask_fn(cols):
+            dv = self._device_valid()
+            return mask_jit(cols, dv) if dv is not None else plain_mask(cols)
 
         edge_tests = 0  # per tested block
         if engine == "pallas" and has_polygon(compiled.device_part):
@@ -1200,12 +1324,14 @@ class DeviceIndex:
         (None/() hides labeled rows — fail closed)."""
         return self._launch_count(query, loose=loose, auths=auths)()
 
+    @_snapshot
     @_scan_scoped
     def _launch_count(self, query, loose=None, auths=None):
         """:meth:`count` up to its device launch: returns the zero-arg
         fetch of the count. The launch reads the resident planes; the
-        fetch only waits for the launched work, so an index that
-        serializes its scans holds its lock for the launch alone."""
+        fetch only waits for the launched work, so the snapshot
+        (``_snapshot``) covers the launch alone and one count's copy
+        overlaps the next count's launch."""
         import jax.numpy as jnp
 
         from geomesa_tpu.failpoints import fail_point
@@ -1293,6 +1419,7 @@ class DeviceIndex:
 
     # -- micro-batch scan fusion (device query scheduler) ------------------
 
+    @_snapshot
     def fused_loose_counts(self, queries, loose: "bool | None" = None):
         """Answer Q compatible loose queries in ONE batched device
         launch: each query's z-range set stacks along a leading query
@@ -1309,6 +1436,7 @@ class DeviceIndex:
             return None
         return [int(v) for v in np.asarray(out)]
 
+    @_snapshot
     def fused_loose_query(self, queries, loose: "bool | None" = None):
         """Batched sibling of :meth:`query`: one device launch computes
         the (Q, n) hit matrix, then per-query host takes demux the rows.
@@ -1481,6 +1609,7 @@ class DeviceIndex:
             )
             return out[: len(lbs)]
 
+    @_snapshot
     @_scan_scoped
     def mask(
         self, query, loose: "bool | None" = None, auths=None
@@ -1514,6 +1643,7 @@ class DeviceIndex:
             m = out
         return self._apply_auths_np(m, auths)
 
+    @_snapshot
     def query(self, query, loose: "bool | None" = None, auths=None):
         """FeatureBatch of hits (host-side take over the device mask)."""
         return self._host_rows().take(
@@ -1670,6 +1800,7 @@ class DeviceIndex:
             out["density"] = None
         return out
 
+    @_snapshot
     def window_union_query(self, envs, times=None, auths=None, base=None):
         """Candidate rows matching ANY of m runtime windows in ONE
         dispatch — the corridor/buffer coarse pass (tube select: one
@@ -1807,6 +1938,7 @@ class DeviceIndex:
             )[: self._staged_len()]
         return self._host_rows().take(np.nonzero(mask)[0])
 
+    @_snapshot
     def knn(
         self,
         px: float,
@@ -1910,6 +2042,7 @@ class DeviceIndex:
         idx, d2 = idx[ok][:k], d2[ok][:k]
         return self._host_rows().take(idx), np.sqrt(d2.astype(np.float64))
 
+    @_snapshot
     def window_pairs_query(self, envs, auths=None, base=None):
         """Candidate (row, window) PAIRS for m runtime envelope windows —
         the device coarse pass of a spatial JOIN (each right-side feature
@@ -2177,6 +2310,7 @@ class DeviceIndex:
 
     # -- pushdown stats (StatsIterator analog) -----------------------------
 
+    @_snapshot
     def stats(
         self, query, spec: str, loose: "bool | None" = None, auths=None
     ):
@@ -2443,6 +2577,7 @@ class DeviceIndex:
 
     # -- pushdown density + BIN (Density/BinAggregating iterator analogs) --
 
+    @_snapshot
     def density(
         self,
         query,
@@ -2551,6 +2686,7 @@ class DeviceIndex:
             grid = grid[: height * width].reshape(height, width)
         return grid
 
+    @_snapshot
     def bin_export(
         self,
         query,
@@ -2656,6 +2792,7 @@ class DeviceIndex:
         self._bin_lanes = {key: mat}  # latest staging only (bounds HBM)
         return mat
 
+    @_snapshot
     def bin_rider(
         self,
         query,
@@ -2756,22 +2893,20 @@ class StreamingDeviceIndex(DeviceIndex):
     Kafka consumer keeping tablet caches warm, SURVEY section 2.6
     Kafka-consumer row [UNVERIFIED - empty reference mount]).
 
-    Device columns live in fixed-capacity buffers with a boolean validity
-    plane. An append is ONE donated jit call per column set
-    (``dynamic_update_slice`` at the current row count); an eviction
-    flips validity bits. Deltas are padded to power-of-two row buckets so
-    jit recompiles stay bounded. When a append would overflow capacity,
-    or dead rows pass ``compact_threshold``, the index compacts: one full
-    restage at double capacity (amortized O(1) per appended row).
+    Device columns live in the base class's padded buffers: an append is
+    ONE donated slot-write (``_write_delta``) at the current row count;
+    an eviction flips validity bits. Deltas pad to power-of-two row
+    buckets so jit recompiles stay bounded. When an append would
+    overflow capacity, or dead rows pass ``compact_threshold``, the
+    index compacts: one full restage at double capacity (amortized O(1)
+    per appended row).
 
-    Scans run the XLA-fused path with the validity plane ANDed in (the
-    Pallas tile kernels do not read a validity column; padded buffers
-    would miscount there). ``attach_live`` applies per-message deltas:
-    Put -> upsert, Remove -> evict, Clear -> full refresh.
+    Scans are the base class's, with the validity plane ANDed in (the
+    Pallas tile kernels take it as an input). ``self._lock`` is the
+    snapshot every read holds (``_snapshot``) and every mutation takes.
+    ``attach_live`` applies per-message deltas: Put -> upsert, Remove ->
+    evict, Clear -> full refresh.
     """
-
-    #: smallest device append bucket (rows); tiny puts pad up to this
-    MIN_DELTA_ROWS = 256
 
     def __init__(
         self,
@@ -2788,7 +2923,6 @@ class StreamingDeviceIndex(DeviceIndex):
         self.compact_threshold = compact_threshold
         self.restages = 0  # full restages (init, growth, compaction)
         self.delta_appends = 0  # appends served by the delta path
-        self._append_jit = None
         self._evict_jit = None
         # live-store listeners run OUTSIDE the store's lock (stream/live.py
         # invokes callbacks unlocked, possibly from several producer
@@ -2822,44 +2956,24 @@ class StreamingDeviceIndex(DeviceIndex):
     def _install_locked(self, batch, min_cap: int = 0) -> None:
         import jax.numpy as jnp
 
-        self._bin_range = None
-        self._bt_base = None
-        self._visid_np = None
-        batch, cols = self._stage_checked(batch)
-        n = len(batch)
+        cols = self._stage_full(batch)
+        n = self._n
         cap = _next_pow2(
             max(n, min_cap, self._capacity_hint or 0, self.MIN_DELTA_ROWS)
         )
-        self._cols = {
-            k: jnp.concatenate([v, jnp.zeros(cap - n, v.dtype)])
-            if cap > n
-            else v
-            for k, v in cols.items()
-        }
+        self._cols = _pad_rows(cols, cap)
         self._valid = jnp.arange(cap) < n
         self._cap = cap
-        self._n = n
         self._n_dead = 0
-        self._parts = [batch]
-        self._host_cache = batch
         self._valid_np = np.ones(n, dtype=bool)
-        self._row_of = {f: i for i, f in enumerate(batch.fids.tolist())}
+        self._row_of = {
+            f: i for i, f in enumerate(self._host_rows().fids.tolist())
+        }
         self.restages += 1
-
-    def _host(self):
-        if self._host_cache is None:
-            from geomesa_tpu.features.batch import FeatureBatch
-
-            self._host_cache = (
-                self._parts[0]
-                if len(self._parts) == 1
-                else FeatureBatch.concat(self._parts)
-            )
-        return self._host_cache
 
     def _live_rows(self):
         """Host batch of only the live (non-evicted) rows."""
-        return self._host().take(np.nonzero(self._valid_np)[0])
+        return self._host_rows().take(np.nonzero(self._valid_np)[0])
 
     # -- deltas ------------------------------------------------------------
 
@@ -2874,85 +2988,38 @@ class StreamingDeviceIndex(DeviceIndex):
             self._append_locked(batch)
 
     def _append_locked(self, batch) -> None:
-        import jax
-        import jax.numpy as jnp
-
         from geomesa_tpu.features.batch import FeatureBatch
 
         m = len(batch)
         if m == 0:
             return
-        pad = max(_next_pow2(m), self.MIN_DELTA_ROWS)
-        if self._n + pad > self._cap:
+        pad = self._delta_pad(m)
+        n0 = self._n
+        if n0 + pad > self._cap:
             # grow: compact out dead rows, double capacity for headroom
             merged = FeatureBatch.concat([self._live_rows(), batch])
             self._install(merged, min_cap=2 * len(merged))
             return
         try:
             delta = self._stage_batch(batch)  # widens _bin_range / vocab
-        except _VisOverflow:
-            # vocabulary overflow mid-stream: full restage applies the
-            # public-only fallback consistently
+        except (_VisOverflow, _BtRebase):
+            # vocabulary overflow mid-stream (the restage applies the
+            # public-only fallback consistently), or delta bins outside
+            # the packed bt window (the restage repacks around a new
+            # bin_base)
+            delta = None
+        if delta is None or not self._write_delta(batch, delta, pad):
+            # (or the delta brought a plane with no slot, e.g. the first
+            # labeled rows of an unlabeled stream: dropping it would
+            # serve labeled rows as public)
             merged = FeatureBatch.concat([self._live_rows(), batch])
             self._install(merged, min_cap=self._cap)
             return
-        except _BtRebase:
-            # delta bins precede (or overflow) the packed bt window: the
-            # bt plane repacks around a new bin_base in one full restage
-            merged = FeatureBatch.concat([self._live_rows(), batch])
-            self._install(merged, min_cap=self._cap)
-            return
-        if set(delta) - set(self._cols):
-            # the delta introduced a NEW plane (first labeled rows on a
-            # previously unlabeled stream): the fixed buffers have no slot
-            # for it — silently dropping it would serve labeled rows as
-            # public. Full restage instead.
-            merged = FeatureBatch.concat([self._live_rows(), batch])
-            self._install(merged, min_cap=self._cap)
-            return
-        delta = {
-            k: jnp.concatenate([v, jnp.zeros(pad - m, v.dtype)])
-            if pad > m
-            else v
-            for k, v in delta.items()
-        }
-        if not delta:
-            # no stageable planes (e.g. all-string schema): the device
-            # side is just the validity plane
-            upd = (jnp.arange(pad) < m) if pad > m else jnp.ones(m, bool)
-            self._valid = jax.lax.dynamic_update_slice_in_dim(
-                self._valid, upd, self._n, 0
-            )
-            self._finish_append(batch, m)
-            return
-        if self._append_jit is None:
-            def _append(cols, valid, delta, n, m):
-                out = {
-                    k: jax.lax.dynamic_update_slice_in_dim(
-                        buf, delta[k].astype(buf.dtype), n, 0
-                    )
-                    for k, buf in cols.items()
-                }
-                upd = jnp.arange(next(iter(delta.values())).shape[0]) < m
-                return out, jax.lax.dynamic_update_slice_in_dim(
-                    valid, upd, n, 0
-                )
-
-            self._append_jit = jax.jit(_append, donate_argnums=(0, 1))
-        self._cols, self._valid = self._append_jit(
-            self._cols, self._valid, delta, self._n, m
-        )
-        self._finish_append(batch, m)
-
-    def _finish_append(self, batch, m: int) -> None:
-        self._parts.append(batch)
-        self._host_cache = None
         self._valid_np = np.concatenate(
             [self._valid_np, np.ones(m, dtype=bool)]
         )
         for i, f in enumerate(batch.fids.tolist()):
-            self._row_of[f] = self._n + i
-        self._n += m
+            self._row_of[f] = n0 + i
         self.delta_appends += 1
 
     def evict(self, fids) -> None:
@@ -3001,7 +3068,9 @@ class StreamingDeviceIndex(DeviceIndex):
 
     def clear(self) -> None:
         with self._lock:
-            self._install(self._parts[0].take(np.array([], dtype=np.int64)))
+            self._install(
+                self._host_parts[0].take(np.array([], dtype=np.int64))
+            )
 
     def refresh_delta(self, batch) -> str:
         """Streamed-append hook: fresh fids delta-append — one donated
@@ -3051,109 +3120,11 @@ class StreamingDeviceIndex(DeviceIndex):
 
         return detach
 
-    # -- query hooks (scan bodies live in DeviceIndex) ---------------------
-
-    def count(self, query, loose: "bool | None" = None, auths=None) -> int:
-        # the launch reads the donated buffers; the fetch waits outside
-        # the lock, so one count's copy overlaps the next count's launch
-        with self._lock:
-            fetch = self._launch_count(query, loose=loose, auths=auths)
-        return fetch()
-
-    def mask(
-        self, query, loose: "bool | None" = None, auths=None
-    ) -> np.ndarray:
-        with self._lock:
-            return super().mask(query, loose=loose, auths=auths)
-
-    def query(self, query, loose: "bool | None" = None, auths=None):
-        with self._lock:
-            return super().query(query, loose=loose, auths=auths)
-
-    def stats(
-        self, query, spec: str, loose: "bool | None" = None, auths=None
-    ):
-        with self._lock:
-            return super().stats(query, spec, loose=loose, auths=auths)
-
-    def density(self, query, envelope, width, height,
-                weight_attr=None, loose=None, auths=None):
-        with self._lock:  # scans race donated-buffer mutations otherwise
-            return super().density(
-                query, envelope, width, height,
-                weight_attr=weight_attr, loose=loose, auths=auths,
-            )
-
-    def bin_export(self, query, track_attr, dtg_attr=None, geom_attr=None,
-                   label_attr=None, sort=False, loose=None, auths=None):
-        # one lock span across mask + host-column reads: the host mirror
-        # and the device mask must come from the same snapshot
-        with self._lock:
-            return super().bin_export(
-                query, track_attr, dtg_attr=dtg_attr, geom_attr=geom_attr,
-                label_attr=label_attr, sort=sort, loose=loose, auths=auths,
-            )
-
-    def bin_rider(self, query, track_attr, dtg_attr=None, geom_attr=None,
-                  label_attr=None, sort=False, loose=None, auths=None):
-        # lane matrix + device mask must come from the same staging
-        with self._lock:
-            return super().bin_rider(
-                query, track_attr, dtg_attr=dtg_attr, geom_attr=geom_attr,
-                label_attr=label_attr, sort=sort, loose=loose, auths=auths,
-            )
-
-    def window_union_query(self, envs, times=None, auths=None, base=None):
-        # (bbox_window_query delegates here, so this one lock covers both)
-        with self._lock:
-            return super().window_union_query(
-                envs, times=times, auths=auths, base=base
-            )
-
-    def knn(self, px, py, k, query=None, auths=None, max_radius_deg=45.0):
-        with self._lock:
-            return super().knn(
-                px, py, k, query=query, auths=auths,
-                max_radius_deg=max_radius_deg,
-            )
-
-    def window_pairs_query(self, envs, auths=None, base=None):
-        with self._lock:
-            return super().window_pairs_query(envs, auths=auths, base=base)
-
-    def fused_loose_counts(self, queries, loose: "bool | None" = None):
-        with self._lock:
-            return super().fused_loose_counts(queries, loose=loose)
-
-    def fused_loose_query(self, queries, loose: "bool | None" = None):
-        # one lock span across launch + host takes: the demuxed rows must
-        # come from the same snapshot the device mask was computed on
-        with self._lock:
-            return super().fused_loose_query(queries, loose=loose)
-
     def __len__(self) -> int:
         return self._n - self._n_dead
 
-    @property
-    def nbytes(self) -> int:
-        return int(
-            sum(v.nbytes for v in self._cols.values()) + self._valid.nbytes
-        )
-
-    def _host_rows(self):
-        return self._host()
-
     def _host_valid(self):
         return self._valid_np
-
-    def _device_valid(self):
-        return self._valid
-
-    def _staged_len(self) -> int:
-        return self._n
-
-    # _make_scan_fns: the base implementation ANDs _device_valid() (read
-    # at call time, so appends/evictions replacing self._valid apply)
 
 
 class ShardedDeviceIndex(DeviceIndex):
@@ -3172,8 +3143,14 @@ class ShardedDeviceIndex(DeviceIndex):
     ``NamedSharding`` over the ``shard`` axis — shard s holds the s-th
     globally-sorted key range. Schemas without a spatial key shard
     positionally. Rows pad to a shard multiple at the GLOBAL TAIL with a
-    device validity plane masking the padding (the streaming-buffer
-    discipline), so every inherited scan stays exact.
+    device validity plane masking the padding (the base class's padded
+    buffers), so every inherited scan stays exact.
+
+    Reads are the base class's, under its one read discipline
+    (``_snapshot``): ``self._lock`` is the snapshot, and this index's
+    ``_read_scope`` adds one ``mesh.scan`` span and one
+    ``geomesa_mesh_launches_total`` count per outermost read. Only
+    ``query`` is its own, for the per-shard compaction.
 
     A failed mesh sort degrades to the host sort (stamped
     ``mesh-degraded``, counted) rather than failing the refresh; a failed
@@ -3217,8 +3194,7 @@ class ShardedDeviceIndex(DeviceIndex):
         self._axis = "shard"
         self._n_shards = int(self._mesh.shape[self._axis])
         self._replicas = int(dict(self._mesh.shape).get("replica", 1))
-        self._dev_valid = None
-        self._n_staged = 0
+        self._in_read = False  # inside an outermost read (_read_scope)
         self._rid_plane = None
         self._shards: list = []
         self._build_seconds = 0.0
@@ -3230,13 +3206,6 @@ class ShardedDeviceIndex(DeviceIndex):
         #: batch-serving default)
         self._reserve_rows = max(int(reserve_rows), 0)
         self._deltas = 0  # streamed delta refreshes since last restage
-        self._delta_jits: dict = {}
-        #: host-mirror parts: deltas append here and the concat is
-        #: DEFERRED to the next host-side read (_host_rows) — an eager
-        #: per-delta concat would copy the whole mirror per append,
-        #: O(total) on the ack path (the StreamingDeviceIndex _parts
-        #: discipline)
-        self._host_parts: list = []
         super().__init__(
             store, type_name, columns, z_planes=z_planes, dim_planes=False
         )
@@ -3269,11 +3238,7 @@ class ShardedDeviceIndex(DeviceIndex):
             order = self._mesh_order(batch)
             if order is not None:
                 batch = batch.take(order)
-            self._bin_range = None
-            self._bt_base = None
-            self._visid_np = None
-            self._host_batch, cols = self._stage_checked(batch)
-            self._cols = self._shard_cols(cols)
+            self._cols = self._shard_cols(self._stage_full(batch))
         self._build_seconds = _time.perf_counter() - t0
         metrics.mesh_build_seconds.observe(self._build_seconds)
         metrics.mesh_shards.set(self._n_shards)
@@ -3351,9 +3316,7 @@ class ShardedDeviceIndex(DeviceIndex):
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        n = len(self._host_batch)
-        self._host_parts = [self._host_batch]
-        self._n_staged = n
+        n = self._n
         self._deltas = 0
         self._fids_seen = None  # delta duplicate-check set: rebuild lazily
         # reserve_rows of delta headroom, rounded to a shard multiple:
@@ -3363,7 +3326,7 @@ class ShardedDeviceIndex(DeviceIndex):
         pad = (want - n) + ((-want) % self._n_shards)
         cap = n + pad
         if cap == 0:
-            self._dev_valid = None
+            self._valid = None
             self._rid_plane = None
             return {k: jnp.asarray(np.asarray(v)) for k, v in cols.items()}
         sharding = NamedSharding(self._mesh, P(self._axis))
@@ -3383,7 +3346,7 @@ class ShardedDeviceIndex(DeviceIndex):
                     [a, np.zeros((pad,) + a.shape[1:], a.dtype)]
                 )
             out[k] = jax.device_put(a, sharding)
-        self._dev_valid = jax.device_put(np.arange(cap) < n, sharding)
+        self._valid = jax.device_put(np.arange(cap) < n, sharding)
         self._rid_plane = jax.device_put(
             np.arange(cap, dtype=np.uint32), sharding
         )
@@ -3408,14 +3371,14 @@ class ShardedDeviceIndex(DeviceIndex):
         from geomesa_tpu.index.api import ShardMeta
 
         self._shards = []
-        n = self._n_staged
+        n = self._n
         # REAL plane capacity (reserve_rows headroom included): the
         # per-shard slot width comes from the staged layout, not the
         # no-reserve formula — with reserve on, real rows concentrate
         # in the leading shards and the manifest must say so
         cap = (
-            int(self._dev_valid.shape[0])
-            if self._dev_valid is not None
+            int(self._valid.shape[0])
+            if self._valid is not None
             else n
         )
         per = cap // self._n_shards if self._n_shards and cap else 0
@@ -3455,7 +3418,7 @@ class ShardedDeviceIndex(DeviceIndex):
             "devices": int(self._mesh.devices.size),
             "shards": self._n_shards,
             "replicas": self._replicas,
-            "rows": self._n_staged,
+            "rows": self._n,
             "resident_bytes": self.nbytes,
             "build_seconds": round(self._build_seconds, 4),
             "build_engine": self._build_engine,
@@ -3488,81 +3451,25 @@ class ShardedDeviceIndex(DeviceIndex):
         return mode
 
     def _delta_locked(self, batch) -> "str | None":
-        import jax
-        import jax.numpy as jnp
-
-        from geomesa_tpu.features.batch import FeatureBatch
-
         m = len(batch)
         if m == 0:
             return "delta"
-        if self._dev_valid is None or not self._host_parts:
+        if self._valid is None:
             return None  # nothing sharded yet: restage establishes it
-        cap = int(self._dev_valid.shape[0])
-        pad = max(_next_pow2(m), 256)
-        if self._n_staged + pad > cap:
+        pad = self._delta_pad(m)
+        if self._n + pad > int(self._valid.shape[0]):
             return None  # reserve spent
         # duplicate fids cannot update in place (no per-row eviction on
         # the sharded planes): restage folds them through the store
         if any(f in self._row_of_sharded() for f in batch.fids.tolist()):
             return None
-        before = set(self._cols)
         delta = self._stage_batch(batch)  # may raise _VisOverflow/_BtRebase
-        if set(delta) != before:
+        if not self._write_delta(batch, delta, pad):
             return None  # a plane with no buffer slot (first labels etc.)
-        delta = {
-            k: jnp.concatenate([v, jnp.zeros(pad - m, v.dtype)])
-            if pad > m
-            else v
-            for k, v in delta.items()
-        }
-        key = (pad, tuple(sorted(delta)))
-        upd_jit = self._delta_jits.get(key)
-        if upd_jit is None:
-            def _upd(cols, valid, dcols, n, rows):
-                out = {
-                    k: jax.lax.dynamic_update_slice_in_dim(
-                        buf, dcols[k].astype(buf.dtype), n, 0
-                    )
-                    for k, buf in cols.items()
-                }
-                live = jnp.arange(pad) < rows
-                return out, jax.lax.dynamic_update_slice_in_dim(
-                    valid, live, n, 0
-                )
-
-            upd_jit = self._delta_jits[key] = jax.jit(
-                _upd, donate_argnums=(0, 1)
-            )
-        self._cols, self._dev_valid = upd_jit(
-            self._cols, self._dev_valid, delta, self._n_staged, m
-        )
-        # host mirror: append the part, concat deferred to _host_rows
-        self._host_parts.append(batch)
-        self._host_batch = None
-        self._n_staged += m
         self._deltas += 1
         for f in batch.fids.tolist():
             self._fids_seen.add(f)
         return "delta"
-
-    def __len__(self) -> int:
-        return self._n_staged
-
-    def _host_rows(self):
-        """Host mirror, materialized lazily: deltas collect in
-        ``_host_parts`` and pay ONE concat at the next host-side read
-        instead of one per append."""
-        if self._host_batch is None:
-            from geomesa_tpu.features.batch import FeatureBatch
-
-            self._host_batch = (
-                self._host_parts[0]
-                if len(self._host_parts) == 1
-                else FeatureBatch.concat(self._host_parts)
-            )
-            self._host_parts = [self._host_batch]
-        return self._host_batch
 
     def _row_of_sharded(self) -> set:
         """Lazily built fid membership set for the delta duplicate
@@ -3576,45 +3483,32 @@ class ShardedDeviceIndex(DeviceIndex):
             self._fids_seen = set(self._host_rows().fids.tolist())
         return self._fids_seen
 
-    # -- scan hooks --------------------------------------------------------
+    # -- reads (the base class's; mesh-wide launches + observability) ------
 
-    def _device_valid(self):
-        return self._dev_valid
-
-    def _staged_len(self) -> int:
-        return self._n_staged
-
-    # _make_scan_fns: the base implementation ANDs _device_valid() (read
-    # at call time), masking the global-tail padding rows
-
-    # -- queries (mesh-wide launches + observability) ----------------------
-
-    def count(self, query, loose: "bool | None" = None, auths=None) -> int:
+    @contextlib.contextmanager
+    def _read_scope(self, op: str):
+        """One ``mesh.scan`` span and one ``geomesa_mesh_launches_total``
+        count per outermost read; a read nested in another (``query`` ->
+        ``mask``) adds neither. ``_snapshot`` enters this under the
+        lock, so the nesting flag needs no lock of its own."""
+        if self._in_read:
+            yield
+            return
         from geomesa_tpu import metrics
         from geomesa_tpu.tracing import span
 
-        with self._lock, span(
-            "mesh.scan", op="count", shards=self._n_shards,
-            type=self.type_name,
-        ):
-            n = super().count(query, loose=loose, auths=auths)
+        self._in_read = True
+        try:
+            with span(
+                "mesh.scan", op=op, shards=self._n_shards,
+                type=self.type_name,
+            ):
+                yield
+        finally:
+            self._in_read = False
         metrics.mesh_launches.inc()
-        return n
 
-    def mask(
-        self, query, loose: "bool | None" = None, auths=None
-    ) -> np.ndarray:
-        from geomesa_tpu import metrics
-        from geomesa_tpu.tracing import span
-
-        with self._lock, span(
-            "mesh.scan", op="mask", shards=self._n_shards,
-            type=self.type_name,
-        ):
-            m = super().mask(query, loose=loose, auths=auths)
-        metrics.mesh_launches.inc()
-        return m
-
+    @_snapshot
     def query(self, query, loose: "bool | None" = None, auths=None):
         """Hit stream via per-shard device-side COMPACTION when the
         key-plane engine answers the filter: each shard compacts its
@@ -3622,107 +3516,19 @@ class ShardedDeviceIndex(DeviceIndex):
         buffers gather ONCE — id bytes instead of a full boolean plane
         for selective queries. Anything else takes the inherited
         mask-and-take path (identical results)."""
-        from geomesa_tpu import metrics
-        from geomesa_tpu.tracing import span
-
-        with self._lock:
-            f = self._parse(query)
-            if (
-                self._resolve_loose(loose)
-                and VIS_ID not in (self._cols or {})
-                and self._staged_len() > 0
-                and self._n_shards > 1
-            ):
-                lb = self._loose_bounds(f)
-                if lb is not None and not (len(lb) == 3 and lb[0] == "dim"):
-                    with span(
-                        "mesh.scan", op="query-compact",
-                        shards=self._n_shards, type=self.type_name,
-                    ):
-                        ids = self._mesh_hits(lb)
-                    if ids is not None:
-                        metrics.mesh_launches.inc()
-                        return self._host_rows().take(ids)
-            return super().query(query, loose=loose, auths=auths)
-
-    def fused_loose_counts(self, queries, loose: "bool | None" = None):
-        from geomesa_tpu import metrics
-        from geomesa_tpu.tracing import span
-
-        with self._lock, span(
-            "mesh.scan", op="fused-count", shards=self._n_shards,
-            queries=len(queries), type=self.type_name,
+        f = self._parse(query)
+        if (
+            self._resolve_loose(loose)
+            and VIS_ID not in (self._cols or {})
+            and self._staged_len() > 0
+            and self._n_shards > 1
         ):
-            out = super().fused_loose_counts(queries, loose=loose)
-        if out is not None:
-            metrics.mesh_launches.inc()
-        return out
-
-    def fused_loose_query(self, queries, loose: "bool | None" = None):
-        from geomesa_tpu import metrics
-        from geomesa_tpu.tracing import span
-
-        with self._lock, span(
-            "mesh.scan", op="fused-query", shards=self._n_shards,
-            queries=len(queries), type=self.type_name,
-        ):
-            out = super().fused_loose_query(queries, loose=loose)
-        if out is not None:
-            metrics.mesh_launches.inc()
-        return out
-
-    # -- rider endpoints (scan bodies live in DeviceIndex; one lock
-    # span so a concurrent refresh cannot republish planes mid-scan) ---
-
-    def stats(
-        self, query, spec: str, loose: "bool | None" = None, auths=None
-    ):
-        with self._lock:
-            return super().stats(query, spec, loose=loose, auths=auths)
-
-    def density(self, query, envelope, width, height,
-                weight_attr=None, loose=None, auths=None):
-        with self._lock:
-            return super().density(
-                query, envelope, width, height,
-                weight_attr=weight_attr, loose=loose, auths=auths,
-            )
-
-    def knn(self, px, py, k, query=None, auths=None, max_radius_deg=45.0):
-        with self._lock:
-            return super().knn(
-                px, py, k, query=query, auths=auths,
-                max_radius_deg=max_radius_deg,
-            )
-
-    def window_union_query(self, envs, times=None, auths=None, base=None):
-        with self._lock:
-            return super().window_union_query(
-                envs, times=times, auths=auths, base=base
-            )
-
-    def window_pairs_query(self, envs, auths=None, base=None):
-        with self._lock:
-            return super().window_pairs_query(envs, auths=auths, base=base)
-
-    def bin_export(self, query, track_attr, dtg_attr=None, geom_attr=None,
-                   label_attr=None, sort=False, loose=None, auths=None):
-        with self._lock:
-            return super().bin_export(
-                query, track_attr, dtg_attr=dtg_attr, geom_attr=geom_attr,
-                label_attr=label_attr, sort=sort, loose=loose, auths=auths,
-            )
-
-    def bin_rider(self, query, track_attr, dtg_attr=None, geom_attr=None,
-                  label_attr=None, sort=False, loose=None, auths=None):
-        # the lane matrix replicates (host-built) while the mask planes
-        # are mesh-sharded; jit propagates the shardings through the
-        # pack launch, so a sharded index still packs in one SPMD pass
-        with self._lock:
-            return super().bin_rider(
-                query, track_attr, dtg_attr=dtg_attr, geom_attr=geom_attr,
-                label_attr=label_attr, sort=sort, loose=loose, auths=auths,
-            )
+            lb = self._loose_bounds(f)
+            if lb is not None and not (len(lb) == 3 and lb[0] == "dim"):
+                ids = self._mesh_hits(lb)
+                if ids is not None:
+                    return self._host_rows().take(ids)
+        return super().query(query, loose=loose, auths=auths)
 
     def _mesh_hits(self, lb) -> "np.ndarray | None":
         """Two sharded launches: per-shard hit counts (cheap scalar
@@ -3749,7 +3555,7 @@ class ShardedDeviceIndex(DeviceIndex):
         if local_n == 0:
             return None
         mf = zscan.kind_mask_fn(kind)
-        has_valid = self._dev_valid is not None
+        has_valid = self._valid is not None
         axis = self._axis
         mesh = self._mesh
         n_shards = self._n_shards
@@ -3784,7 +3590,7 @@ class ShardedDeviceIndex(DeviceIndex):
             self._hits_jits[ckey] = cfn
         operands = list(planes) + [bounds] + ([ids] if binned else [])
         if has_valid:
-            operands.append(self._dev_valid)
+            operands.append(self._valid)
         counts = np.asarray(cfn(*operands))
         top = int(counts.max()) if len(counts) else 0
         if top == 0:
@@ -3818,4 +3624,4 @@ class ShardedDeviceIndex(DeviceIndex):
         out = np.asarray(got)[np.asarray(gvalid)].astype(np.int64)
         # shard buffers concatenate in shard order and each shard's ids
         # ascend, so the stream is globally ascending == nonzero(mask)
-        return out[out < self._n_staged]
+        return out[out < self._n]

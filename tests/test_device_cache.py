@@ -1,6 +1,9 @@
 """Device-resident index: pinned columns, repeated queries, refresh."""
 
+import threading
+
 import numpy as np
+import pytest
 
 from geomesa_tpu.device_cache import DeviceIndex
 from geomesa_tpu.filter.compile import evaluate_host
@@ -298,17 +301,23 @@ class TestStreamingDeviceIndex:
             np.sort(all_batch.fids[expect & live].astype(np.int64)),
         )
 
-    def test_count_fetches_outside_the_lock(self, monkeypatch):
+    @pytest.mark.parametrize("kind", ["streaming", "sharded"])
+    def test_count_fetches_outside_the_lock(self, monkeypatch, kind):
         """The lock covers a count's launch alone: the fetch finds it
-        free, and an append that donates the launched planes before the
-        fetch leaves the launched count's answer as it was."""
-        import threading
-
+        free, and a delta that donates the launched planes before the
+        fetch leaves the launched count's answer as it was. The mesh
+        index mutates through ``refresh_delta`` into its reserve."""
         from geomesa_tpu import device_cache
-        from geomesa_tpu.device_cache import StreamingDeviceIndex
 
         ds = _store(n=5000)
-        di = StreamingDeviceIndex(ds, "t", capacity=1 << 15)
+        if kind == "streaming":
+            di = device_cache.StreamingDeviceIndex(ds, "t", capacity=1 << 15)
+        else:
+            from geomesa_tpu.parallel.mesh import make_mesh
+
+            di = device_cache.ShardedDeviceIndex(
+                ds, "t", mesh=make_mesh(8), reserve_rows=4096
+            )
         _, expect = _oracle(ds, self.ECQL)
         free = []
         fetch = device_cache._fetch
@@ -333,8 +342,11 @@ class TestStreamingDeviceIndex:
         sft = ds.get_schema("t")
         b = self._batch(sft, 500, seed=41, fid0=100_000)
         ds.write("t", dict(b.columns), fids=b.fids)
-        di.append(b)
-        assert di.delta_appends == 1
+        if kind == "streaming":
+            di.append(b)
+            assert di.delta_appends == 1
+        else:
+            assert di.refresh_delta(b) == "delta"
         assert pending() == int(expect.sum())
         _, after = _oracle(ds, self.ECQL)
         assert di.count(self.ECQL) == int(after.sum())
@@ -1194,3 +1206,101 @@ class TestPerAuthResident:
         )
         got = set(di.query(ecql, auths=("secret",)).fids.tolist())
         assert got == want
+
+
+# -- the read discipline: every public read holds the index's snapshot ------
+
+_SNAP_ECQL = TestStreamingDeviceIndex.ECQL
+_SNAP_WINDOWS = np.array([[-10.0, 35.0, 30.0, 60.0]])
+
+#: the public reads of DeviceIndex, each called with small arguments
+_READS = {
+    "count": lambda di: di.count(_SNAP_ECQL),
+    "mask": lambda di: di.mask(_SNAP_ECQL),
+    "query": lambda di: di.query(_SNAP_ECQL),
+    "stats": lambda di: di.stats(_SNAP_ECQL, "Count()"),
+    "density": lambda di: di.density(
+        _SNAP_ECQL, __import__("geomesa_tpu.geom").geom.Envelope(
+            -10, 35, 30, 60), 32, 32),
+    "bin_export": lambda di: di.bin_export(_SNAP_ECQL, track_attr="name"),
+    "bin_rider": lambda di: di.bin_rider(_SNAP_ECQL, track_attr="val"),
+    "window_union_query": lambda di: di.window_union_query(_SNAP_WINDOWS),
+    "knn": lambda di: di.knn(0.0, 0.0, 5),
+    "window_pairs_query": lambda di: di.window_pairs_query(_SNAP_WINDOWS),
+    "fused_loose_counts": lambda di: di.fused_loose_counts(
+        [_SNAP_ECQL] * 2, loose=True),
+    "fused_loose_query": lambda di: di.fused_loose_query(
+        [_SNAP_ECQL] * 2, loose=True),
+}
+
+
+@pytest.fixture(scope="module")
+def resident_index():
+    """One warm index per kind over one store, built on first use."""
+    from geomesa_tpu.device_cache import (
+        ShardedDeviceIndex,
+        StreamingDeviceIndex,
+    )
+    from geomesa_tpu.parallel.mesh import make_mesh
+
+    ds = _store(n=4000)
+    built: dict = {}
+
+    def get(kind: str):
+        if kind not in built:
+            if kind == "streaming":
+                built[kind] = StreamingDeviceIndex(ds, "t", z_planes=True)
+            elif kind == "sharded":
+                built[kind] = ShardedDeviceIndex(ds, "t", mesh=make_mesh(8))
+            else:
+                built[kind] = DeviceIndex(ds, "t", z_planes=True)
+        return built[kind]
+
+    return get
+
+
+def _start_read(di, read: str):
+    """Run one read on another thread: (thread, done event, errors)."""
+    done = threading.Event()
+    errors: list = []
+
+    def run():
+        try:
+            _READS[read](di)
+        except BaseException as e:  # noqa: BLE001 - reported by the test
+            errors.append(e)
+        finally:
+            done.set()
+
+    t = threading.Thread(target=run)
+    t.start()
+    return t, done, errors
+
+
+@pytest.mark.parametrize("read", sorted(_READS))
+@pytest.mark.parametrize("kind", ["streaming", "sharded"])
+def test_every_read_takes_the_snapshot(resident_index, kind, read):
+    """While another thread holds the index's lock -- the one appends,
+    evictions and restages take -- a read started on this index does
+    not finish; released, it completes."""
+    di = resident_index(kind)
+    _READS[read](di)  # warm: compiles stay out of the timed window
+    with di._lock:
+        t, done, errors = _start_read(di, read)
+        assert not done.wait(0.05), f"{read} ran without the snapshot"
+    t.join(timeout=120)
+    assert done.is_set() and errors == []
+
+
+def test_base_index_reads_take_no_lock(resident_index):
+    """The base index never mutates its planes in place: its snapshot
+    holds nothing, and every read completes while it is entered."""
+    di = resident_index("base")
+    for read in _READS.values():
+        read(di)  # warm: compiles stay on this thread
+    with di._lock:
+        for read in sorted(_READS):
+            t, done, errors = _start_read(di, read)
+            assert done.wait(120), f"{read} blocked on the base index"
+            t.join()
+            assert errors == [], read

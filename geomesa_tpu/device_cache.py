@@ -1215,12 +1215,13 @@ class DeviceIndex:
             dv = self._device_valid()
             return mask_jit(cols, dv) if dv is not None else plain_mask(cols)
 
-        edge_tests = 0  # per tested block
+        edge_tests = loop_tests = 0  # per tested block
         if engine == "pallas" and has_polygon(compiled.device_part):
             from geomesa_tpu.ops import pallas_scan as ps
 
-            edge_tests = ps.SKIP_ROWS * ps.LANES * ps.padded_edges(
-                compiled.device_part)
+            rows = ps.SKIP_ROWS * ps.LANES
+            edge_tests = rows * ps.padded_edges(compiled.device_part)
+            loop_tests = rows * ps.looped_edges(compiled.device_part)
 
         def count_fn(cols):
             out = dispatch(cols)
@@ -1232,6 +1233,7 @@ class DeviceIndex:
                     metrics.pip_blocks.inc_key(metrics.PIP_TESTED, tested)
                     metrics.pip_blocks.inc_key(metrics.PIP_SKIPPED, skipped)
                     metrics.pip_edge_tests.inc(tested * edge_tests)
+                    metrics.pip_edge_loop_tests.inc(tested * loop_tests)
                     metrics.pip_tiles.inc_key(metrics.PIP_WALKED, walked)
                     metrics.pip_tiles.inc_key(metrics.PIP_TILE_SKIPPED, dead)
                 return got[0]

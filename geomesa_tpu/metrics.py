@@ -508,8 +508,10 @@ slow_queries = REGISTRY.counter(
 
 # point-in-polygon filters on the resident count path: which engine
 # answered (pallas kernel, XLA broadcast, host), and the polygon kernel's
-# work: rows of the blocks it tested times their padded edges, and its
-# blocks by whether the pretests (envelope, window) let it skip the edges
+# work: rows of the blocks it tested times their padded edges, and times
+# the edges its loop ran (each zone's real edges, rounded up to the
+# loop's unroll), and its blocks by whether the pretests (envelope,
+# window) let it skip the edges
 pip_queries = REGISTRY.counter(
     "geomesa_pip_queries_total",
     "resident counts of a point-in-polygon filter, by engine",
@@ -517,6 +519,10 @@ pip_queries = REGISTRY.counter(
 pip_edge_tests = REGISTRY.counter(
     "geomesa_pip_edge_tests_total",
     "polygon kernel: rows of tested blocks x padded polygon edges",
+)
+pip_edge_loop_tests = REGISTRY.counter(
+    "geomesa_pip_edge_loop_tests_total",
+    "polygon kernel: rows of tested blocks x polygon edges the loop ran",
 )
 pip_blocks = REGISTRY.counter(
     "geomesa_pip_blocks_total",

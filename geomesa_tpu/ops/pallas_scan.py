@@ -17,12 +17,14 @@ over :data:`MAX_KERNEL_EDGES` edges) fall back to the XLA-fused jnp path
 in filter/compile.py -- same semantics, same staged columns.
 
 A filter with a point-in-polygon conjunct takes the polygon kernel
-(:func:`_build_pip_scan`): the polygon's edge table, its envelope and
-the DURING/BBOX bounds ride in SMEM as runtime operands, so one compiled
-program serves every filter of the same shape and edge bucket, and the
-edge loop runs only on the row blocks where some row passes the cheap
-exact pretests the predicate implies (the envelope, the time window);
-a grid tile where no row passes them skips its block walk.
+(:func:`_build_pip_scan`): the polygon's edge table, its envelope, the
+edge loop's trip count and the DURING/BBOX bounds ride in SMEM as
+runtime operands, so one compiled program serves every filter of the
+same shape and edge bucket, the loop stops at each polygon's own edges
+(not at its bucket's), and it runs only on the row blocks where some
+row passes the cheap exact pretests the predicate implies (the
+envelope, the time window); a grid tile where no row passes them skips
+its block walk.
 
 On CPU jax (tests / CI) the kernel runs in interpret mode, so the whole
 suite exercises the identical kernel code without a TPU.
@@ -40,8 +42,8 @@ LANES = 128
 #: a polygon's edge table is padded to a bucket of the shape ladder (a
 #: power of two by default) between these, with degenerate y_lo == y_hi
 #: edges, which never straddle: one compiled kernel serves every polygon
-#: of a bucket. The table rides in SMEM; polygons over the largest
-#: bucket take the XLA path
+#: of a bucket, its loop running to the polygon's real edges. The table
+#: rides in SMEM; polygons over the largest bucket take the XLA path
 MIN_EDGE_BUCKET = 16
 MAX_KERNEL_EDGES = 4096
 #: sublane rows of a skip block of the polygon kernel (x 128 lanes): the
@@ -124,6 +126,16 @@ def edge_bucket(n_edges: int) -> int:
     return -(-b // _EDGE_UNROLL) * _EDGE_UNROLL
 
 
+def _real_edges(geometry) -> int:
+    """A polygon's edges before its table is padded."""
+    return sum(len(r) - 1 for r in geometry.rings())
+
+
+def _loop_steps(n_edges: int) -> int:
+    """Steps of the edge loop over ``n_edges`` real edges."""
+    return -(-n_edges // _EDGE_UNROLL)
+
+
 def edge_table(rings) -> np.ndarray:
     """(4, bucket) float32 table of closed rings' edges: per edge its
     lower y, upper y, the x at the lower y, and dx/dy. Endpoints are
@@ -166,7 +178,8 @@ class _TilePlan:
 
     With ``lift`` (the polygon kernel) BBOX bounds, polygon envelopes and
     edge tables go to ``floats``, the int64 bounds of DURING/BETWEEN to
-    ``hi``/``lo`` as int32/uint32 words; ``q`` is (floats, hi, lo), read
+    ``hi``/``lo`` as int32/uint32 words, each polygon's edge-loop trip
+    count to ``hi`` (its ``lo`` word unused); ``q`` is (floats, hi, lo), read
     from SMEM at run time, so the filters of one ``key`` (attributes,
     ops, edge buckets, and the literals that stay in the program) share
     one program. Without it (the bbox kernel) every literal is baked in
@@ -276,14 +289,19 @@ class _TilePlan:
             y0, y1 = _f32_bounds(e.ymin, e.ymax, outward=True)
             env = _box_fn(node.attr, self._add_floats([x0, y0, x1, y1]))
             off = self._add_floats(table)
+            # the loop's trip count is an operand: the key keeps the bucket
+            k = len(self.hi)
+            self.hi.append(_loop_steps(_real_edges(node.geometry)))
+            self.lo.append(0)
             self._key.append(f"Intersects({node.attr},{node.op},{n_edges})")
             ax, ay = f"{node.attr}__x", f"{node.attr}__y"
 
-            def inside(cols, q, env=env, off=off, n=n_edges, ax=ax, ay=ay):
+            def inside(cols, q, env=env, off=off, n=n_edges, k=k, ax=ax,
+                       ay=ay):
                 # every hit lies in the envelope: the crossing parity can
                 # flip a point an ulp outside it, the envelope cannot
                 return env(cols, q) & _crossing_parity(
-                    cols[ax], cols[ay], q[0], off, n
+                    cols[ax], cols[ay], q[0], off, n, q[1][k]
                 )
 
             if node.op == "disjoint":
@@ -346,11 +364,13 @@ def _box_fn(attr: str, off: int):
     return box
 
 
-def _crossing_parity(px, py, fq, off: int, n_edges: int):
+def _crossing_parity(px, py, fq, off: int, n_edges: int, steps):
     """Crossing-number parity of points against the edge table at
-    ``off`` of the float table, the edges read from SMEM in a loop: an
-    edge crosses the ray east of a point when y_lo <= py < y_hi and px
-    lies west of the edge at py."""
+    ``off`` of the float table (``n_edges`` wide, the bucket), the edges
+    read from SMEM in a loop of ``steps`` (a traced int32: the polygon's
+    real edges over the unroll, rounded up; the padding edges past them
+    never straddle): an edge crosses the ray east of a point when
+    y_lo <= py < y_hi and px lies west of the edge at py."""
     import jax
     import jax.numpy as jnp
 
@@ -371,8 +391,7 @@ def _crossing_parity(px, py, fq, off: int, n_edges: int):
         return odd
 
     odd = jax.lax.fori_loop(
-        jnp.int32(0), jnp.int32(n_edges // _EDGE_UNROLL), edges,
-        jnp.zeros(px.shape, jnp.int32),
+        jnp.int32(0), steps, edges, jnp.zeros(px.shape, jnp.int32),
     )
     return odd != jnp.int32(0)
 
@@ -544,17 +563,29 @@ def _pip_kernels(cheap, full, cols: list, br: int, interpret: bool):
     return jax.jit(pip_count), jax.jit(pip_mask)
 
 
-def padded_edges(f: ast.Filter) -> int:
-    """The polygon kernel's edges for a filter: each polygon's edge
-    count padded to its bucket, summed. A row of a tested block is
-    tested against each."""
+def _polygon_edges(f: ast.Filter, edges) -> int:
+    """``edges(real edge count)`` summed over a filter's polygons."""
     if isinstance(f, (ast.And, ast.Or)):
-        return sum(padded_edges(c) for c in f.children)
+        return sum(_polygon_edges(c, edges) for c in f.children)
     if isinstance(f, ast.Not):
-        return padded_edges(f.child)
+        return _polygon_edges(f.child, edges)
     if isinstance(f, ast.Intersects) and hasattr(f.geometry, "rings"):
-        return edge_bucket(sum(len(r) - 1 for r in f.geometry.rings()))
+        return edges(_real_edges(f.geometry))
     return 0
+
+
+def padded_edges(f: ast.Filter) -> int:
+    """The width of the polygon kernel's edge tables for a filter: each
+    polygon's edge count padded to its bucket, summed (the bucket of the
+    program it compiles)."""
+    return _polygon_edges(f, edge_bucket)
+
+
+def looped_edges(f: ast.Filter) -> int:
+    """The edges the polygon kernel's loop runs for a filter: each
+    polygon's edge count rounded up to the loop's unroll, summed. A row
+    of a tested block is tested against each."""
+    return _polygon_edges(f, lambda n: _EDGE_UNROLL * _loop_steps(n))
 
 
 def _build_pip_scan(f, sft, cols, br, interpret):

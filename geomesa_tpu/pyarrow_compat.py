@@ -4,14 +4,16 @@ Observed in this environment (pyarrow + glibc build): if pyarrow is
 FIRST imported on a non-main thread (e.g. an HTTP handler serving an
 Arrow response, or an ingest worker), its native initialization is
 corrupted and a LATER parquet read from the main thread segfaults inside
-``read_table``. Importing pyarrow from the spawning thread before any
-worker threads start avoids it entirely.
+``read_table``, as does a later parquet write on any other thread
+(inside ``ParquetWriter``: a store flush after a prefetched merge read).
+Importing pyarrow from the spawning thread before any worker threads
+start avoids it entirely.
 
 Every component that spawns threads which may touch Arrow/Parquet calls
 ``preload_pyarrow()`` first (server, jobs, partitioned-log consumers,
-parallel frame scans). Importing ``geomesa_tpu`` itself stays
-side-effect free — the preload happens at thread-pool construction, not
-package import.
+parallel frame scans, the store's read-ahead workers and flush
+writers). Importing ``geomesa_tpu`` itself stays side-effect free —
+the preload happens at thread-pool construction, not package import.
 """
 
 from __future__ import annotations

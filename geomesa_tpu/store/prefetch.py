@@ -186,6 +186,7 @@ def prefetch_map(fn, items, config=None, size_of=None):
 
 def _prefetch_threads(fn, items, cfg: PrefetchConfig, size_of):
     from geomesa_tpu import metrics
+    from geomesa_tpu.pyarrow_compat import preload_pyarrow
     from geomesa_tpu.spawn import ContextPool
 
     it = iter(items)
@@ -215,6 +216,10 @@ def _prefetch_threads(fn, items, cfg: PrefetchConfig, size_of):
         return out, b
 
     pending: deque = deque()
+    # the workers read and decode partition files: pyarrow's FIRST import
+    # must happen on this (spawning) thread, or a later parquet write in
+    # this process segfaults (pyarrow_compat contract)
+    preload_pyarrow()
     ex = ContextPool(cfg.workers, thread_name_prefix=WORKER_PREFIX)
     # gauges are updated by DELTA (inc/dec), never set: several
     # pipelines commonly run at once (concurrent queries on a threaded

@@ -1,7 +1,8 @@
 """The point-in-polygon kernel (ops/pallas_scan.py) against the plain
 reference (benchmark/reference.polygon_count), in interpret mode and on
 the XLA path: zones of every edge bucket, multipart zones and holes, a
-time window whose blocks and whole tiles the kernel skips; its tile,
+time window whose blocks and whole tiles the kernel skips; zones well
+below their bucket, whose edge loop stops at their real edges; its tile,
 block and edge counters; the engine the resident count records and its
 counters; one compiled program per edge bucket."""
 
@@ -95,13 +96,30 @@ def _kernel(cql: str, **kw):
     return cf, scan
 
 
-@pytest.mark.parametrize("kind", ["single", "hole", "multipart"])
-@pytest.mark.parametrize("n_edges", [16, 64, 65, 512, 1024])
+def _intersects(n_edges: int, kind: str):
+    """(CQL, reference parts) of INTERSECTS a zone of ``kind``; for
+    ``or``, a single zone OR a 201-edge one apart from it (bucket 256,
+    which no other zone of these tests fills: each polygon's loop runs
+    its own trip count)."""
+    if kind != "or":
+        wkt, parts = zone(n_edges, kind)
+        return f"INTERSECTS(geom, {wkt})", parts
+    wkt, parts = zone(n_edges, "single")
+    far = _ring(201, 120.0, 50.0, 40.0, 20.0)
+    return (f"(INTERSECTS(geom, {wkt}) OR "
+            f"INTERSECTS(geom, POLYGON ({_wkt_ring(far)})))",
+            parts + [[far]])
+
+
+@pytest.mark.parametrize("kind", ["single", "hole", "multipart", "or"])
+@pytest.mark.parametrize("n_edges", [16, 17, 64, 65, 100, 512, 1001, 1024])
 def test_kernel_and_xla_path_match_reference(data, n_edges, kind):
+    """Zones that fill their bucket, and zones well below it whose edge
+    count is no multiple of the loop's unroll (17 in 32, 100 in 128,
+    1,001 in 1,024)."""
     batch, tab = data
-    wkt, parts = zone(n_edges, kind)
-    cf, (count_fn, mask_fn) = _kernel(
-        f"INTERSECTS(geom, {wkt}) AND {WINDOW}", block_rows=32)
+    pred, parts = _intersects(n_edges, kind)
+    cf, (count_fn, mask_fn) = _kernel(f"{pred} AND {WINDOW}", block_rows=32)
     cols = stage_columns(batch, cf.device_cols)
     want = reference.polygon_count(tab, parts, W0, W1)
     assert want > 0
@@ -320,7 +338,8 @@ def _resident(n: int = 4000, cls=None, **kw):
 def _counters():
     return (metrics.pip_blocks.value(outcome="tested"),
             metrics.pip_blocks.value(outcome="skipped"),
-            metrics.pip_edge_tests.value())
+            metrics.pip_edge_tests.value(),
+            metrics.pip_edge_loop_tests.value())
 
 
 def _engines():
@@ -352,13 +371,18 @@ def test_engine_counter_and_one_program_per_bucket(monkeypatch):
 
     sig = "cache.scan:pip:e=128"
     c0 = compiles(sig)
-    for n_edges, day in ((100, 3), (110, 9), (128, 20)):
+    steps = set()
+    for n_edges, day in ((65, 30), (100, 3), (110, 9), (128, 20)):
         wkt, parts = zone(n_edges, "single")
         t0 = T0 + day * DAY
         q = (f"INTERSECTS(geom, {wkt}) AND dtg DURING "
              f"{np.datetime64(t0, 'ms')}Z/{np.datetime64(t0 + 7 * DAY, 'ms')}Z")
         assert di.count(q) == reference.polygon_count(tab, parts, t0,
                                                       t0 + 7 * DAY)
+        # the loop's trip count is an operand, not part of the program
+        plan = pallas_scan._TilePlan(parse_ecql(q), SFT, lift=True)
+        steps.add(plan.hi[0])
+    assert steps == {9, 13, 14, 16}
     assert len([k for k in pallas_scan._PIP_PROGRAMS if "128)" in k[0]]) == 1
     assert compiles(sig) - c0 == 1
 
@@ -370,17 +394,20 @@ def test_engine_counter_and_one_program_per_bucket(monkeypatch):
     assert after["pallas"] == before["pallas"]
 
 
-def test_resident_count_moves_block_and_edge_counters(monkeypatch):
+@pytest.mark.parametrize("n_edges", [200, 256], ids=["below", "fills"])
+def test_resident_count_moves_block_and_edge_counters(monkeypatch, n_edges):
     """A resident count on the polygon kernel moves the block counters
-    by its tested and skipped blocks, and the edge tests by the tested
-    blocks' rows times the padded edges."""
+    by its tested and skipped blocks, the edge tests by the tested
+    blocks' rows times the padded edges (256), and the loop's edge tests
+    by those rows times the edges its loop ran: the zone's own, alike
+    where the zone fills its bucket."""
     monkeypatch.setattr(compile_mod, "_on_tpu", lambda: True)
     di, tab = _resident()
-    wkt, parts = zone(200, "single")
+    wkt, parts = zone(n_edges, "single")
     cql = f"INTERSECTS(geom, {wkt}) AND {WINDOW}"
-    t0, s0, e0 = _counters()
+    t0, s0, e0, l0 = _counters()
     assert di.count(cql) == reference.polygon_count(tab, parts, W0, W1)
-    t1, s1, e1 = _counters()
+    t1, s1, e1, l1 = _counters()
     cols = compile_filter(parse_ecql(cql), SFT).device_cols
     br = pallas_scan._pick_block_rows(len(cols))
     blocks = -(-di._staged_len() // (br * pallas_scan.LANES)) * (
@@ -389,6 +416,7 @@ def test_resident_count_moves_block_and_edge_counters(monkeypatch):
     assert t1 - t0 > 0
     rows = pallas_scan.SKIP_ROWS * pallas_scan.LANES
     assert e1 - e0 == (t1 - t0) * rows * 256
+    assert l1 - l0 == (t1 - t0) * rows * n_edges
 
 
 @pytest.mark.parametrize("day,walked", [(19, 1), (400, 0)],
@@ -407,9 +435,9 @@ def test_resident_count_moves_tile_counters(monkeypatch, day, walked):
         return (metrics.pip_tiles.value(outcome="walked"),
                 metrics.pip_tiles.value(outcome="skipped"))
 
-    (w0, s0), (b0, _, _) = tiles(), _counters()
+    (w0, s0), (b0, *_) = tiles(), _counters()
     assert di.count(cql) == reference.polygon_count(tab, parts, t0, t1)
-    (w1, s1), (b1, _, _) = tiles(), _counters()
+    (w1, s1), (b1, *_) = tiles(), _counters()
     assert (w1 - w0, s1 - s0) == (walked, 1 - walked)
     assert (b1 - b0 > 0) == bool(walked)
 

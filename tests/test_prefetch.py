@@ -63,6 +63,48 @@ def test_serial_workers0_spawns_no_threads():
     assert threading.active_count() == before
 
 
+_FIRST_IMPORT_CHILD = """
+import sys, threading
+from geomesa_tpu.store.prefetch import PrefetchConfig, prefetch_map
+
+def read(i):
+    seen = "pyarrow" in sys.modules
+    import pyarrow.parquet  # noqa: F401
+    return seen
+
+seen = list(prefetch_map(read, range(4), PrefetchConfig(workers={workers})))
+
+def write():
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    pq.write_table(pa.table({{"a": [1, 2, 3]}}), pa.BufferOutputStream())
+
+t = threading.Thread(target=write)
+t.start()
+t.join()
+print(all(seen))
+"""
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_workers_never_import_pyarrow_first(workers):
+    """In a fresh process, pyarrow is already imported when a prefetch
+    worker starts: a first import on a worker thread corrupts pyarrow's
+    native init, and the process's next parquet write on another thread
+    segfaults (the crash a flush after a merge read used to hit)."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", _FIRST_IMPORT_CHILD.format(workers=workers)],
+        cwd=root, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["True"]
+
+
 def test_readahead_is_bounded_and_overlaps():
     """At most ``depth`` items are in flight, and with workers > 1 the
     pipeline genuinely overlaps (two fn calls concurrent at some point).

@@ -489,6 +489,9 @@ def test_resident_polygon_count_parses_once_and_counts_its_kernel(
             'geomesa_pip_blocks_total{outcome="skipped"}') == blocks
         assert moved("geomesa_pip_edge_tests_total") == (
             tested * rows * pallas_scan.LANES * 1024)
+        # the zone fills its bucket: the loop runs every padded edge
+        assert moved("geomesa_pip_edge_loop_tests_total") == moved(
+            "geomesa_pip_edge_tests_total")
         # a relation the kernel cannot run is tested on the host
         crosses = cql.replace("INTERSECTS(", "CROSSES(", 1)
         status, _, _ = _get(
